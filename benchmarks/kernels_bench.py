@@ -32,21 +32,21 @@ def main(fast: bool = False):
     # flash attention
     B, S, H, hd = 1, 256, 4, 64
     q = jax.random.normal(key, (B, S, H, hd), jnp.float32)
-    us_p = timeit(flash_attention, q, q, q, impl="pallas")
-    us_r = timeit(flash_attention, q, q, q, impl="ref")
+    us_p = timeit(flash_attention, q, q, q, impl="pallas", interpret=True)
+    us_r = timeit(flash_attention, q, q, q, impl="ref", interpret=True)
     rows.append(("flash_attention", us_p, us_r))
     # decode attention
     q1 = jax.random.normal(key, (2, 8, 64), jnp.float32)
     kc = jax.random.normal(key, (2, 1024, 2, 64), jnp.float32)
-    us_p = timeit(decode_attention, q1, kc, kc, 900, impl="pallas")
-    us_r = timeit(decode_attention, q1, kc, kc, 900, impl="ref")
+    us_p = timeit(decode_attention, q1, kc, kc, 900, impl="pallas", interpret=True)
+    us_r = timeit(decode_attention, q1, kc, kc, 900, impl="ref", interpret=True)
     rows.append(("decode_attention", us_p, us_r))
     # rglru
     la = -jnp.abs(jax.random.normal(key, (2, 512, 256))) * 0.1
     x = jax.random.normal(key, (2, 512, 256))
     h0 = jnp.zeros((2, 256))
-    us_p = timeit(rglru_scan, la, x, h0, impl="pallas")
-    us_r = timeit(rglru_scan, la, x, h0, impl="ref")
+    us_p = timeit(rglru_scan, la, x, h0, impl="pallas", interpret=True)
+    us_r = timeit(rglru_scan, la, x, h0, impl="ref", interpret=True)
     rows.append(("rglru_scan", us_p, us_r))
     for name, us_p, us_r in rows:
         print(f"{name:18s} pallas(interpret) {us_p:10.0f}us  jnp-ref {us_r:10.0f}us")

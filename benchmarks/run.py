@@ -93,6 +93,8 @@ def main() -> None:
                       f"Available targets:")
         raise SystemExit(2)
 
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     from benchmarks import (beyond_ablations, fig4_power_curves,
                             fig5_static_slo, fig6_queueing, fig7_slo_scaling,
                             fig8_dynamic, fig9_cluster_scaling,

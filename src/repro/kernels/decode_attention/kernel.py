@@ -10,7 +10,9 @@ scratch achieves the same reduction without a second pass).
 GQA layout: queries arrive as (B, K, G, hd) — one kernel instance per
 (batch, kv-head); the G query heads sharing that KV head are processed as
 the matmul's row dimension, so the KV block is loaded once per G rows
-(the GQA arithmetic-intensity win, preserved in VMEM).
+(the GQA arithmetic-intensity win, preserved in VMEM). The caches are
+viewed as (B, S, K*hd) (a free reshape), so the k/v block of head k is the
+(bs, hd) column slab k: lane-aligned whenever hd is a multiple of 128.
 """
 from __future__ import annotations
 
@@ -41,7 +43,7 @@ def _dec_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
     @pl.when(start <= pos)
     def _run():
         q = q_ref[0, 0].astype(jnp.float32) * scale        # (G, hd)
-        k = k_ref[0][:, 0, :].astype(jnp.float32)          # (bs, hd)
+        k = k_ref[0].astype(jnp.float32)                   # (bs, hd)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # (G, bs)
         kpos = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where(kpos <= pos, s, NEG_INF)
@@ -50,7 +52,7 @@ def _dec_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
         l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        v = v_ref[0][:, 0, :].astype(jnp.float32)          # (bs, hd)
+        v = v_ref[0].astype(jnp.float32)                   # (bs, hd)
         acc_ref[...] = acc_ref[...] * alpha + \
             jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())))
         m_ref[...] = m_new
@@ -62,7 +64,7 @@ def _dec_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
 
 
 def decode_attention_pallas(q, k_cache, v_cache, pos, *, bs: int = 512,
-                            interpret: bool = True):
+                            interpret: bool):
     """q: (B, Hq, hd); caches (B, S, K, hd); pos scalar int32.
     Returns (B, Hq, hd)."""
     B, Hq, hd = q.shape
@@ -72,6 +74,8 @@ def decode_attention_pallas(q, k_cache, v_cache, pos, *, bs: int = 512,
     assert S % bs == 0
     ns = S // bs
     qg = q.reshape(B, K, G, hd)
+    kf = k_cache.reshape(B, S, K * hd)
+    vf = v_cache.reshape(B, S, K * hd)
     pos_arr = jnp.asarray(pos, jnp.int32).reshape(1)
 
     kernel = functools.partial(_dec_kernel, scale=1.0 / math.sqrt(hd),
@@ -82,8 +86,8 @@ def decode_attention_pallas(q, k_cache, v_cache, pos, *, bs: int = 512,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, 1, G, hd), lambda b, k, s: (b, k, 0, 0)),
-            pl.BlockSpec((1, bs, 1, hd), lambda b, k, s: (b, s, k, 0)),
-            pl.BlockSpec((1, bs, 1, hd), lambda b, k, s: (b, s, k, 0)),
+            pl.BlockSpec((1, bs, hd), lambda b, k, s: (b, s, k)),
+            pl.BlockSpec((1, bs, hd), lambda b, k, s: (b, s, k)),
         ],
         out_specs=pl.BlockSpec((1, 1, G, hd), lambda b, k, s: (b, k, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, K, G, hd), q.dtype),
@@ -93,5 +97,5 @@ def decode_attention_pallas(q, k_cache, v_cache, pos, *, bs: int = 512,
             pltpu.VMEM((G, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(pos_arr, qg, k_cache, v_cache)
+    )(pos_arr, qg, kf, vf)
     return out.reshape(B, Hq, hd)

@@ -84,7 +84,7 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 def flash_attention_pallas(q, k, v, *, causal: bool = True,
                            window: Optional[int] = None,
                            bq: int = 128, bk: int = 128,
-                           interpret: bool = True):
+                           interpret: bool):
     """q,k,v: (B, S, H, hd) with identical H (GQA expansion done by caller).
     Returns (B, S, H, hd)."""
     B, S, H, hd = q.shape

@@ -26,7 +26,7 @@ def _expand_gqa(q, k, v):
                                              "bq", "bk", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True,
                     window: Optional[int] = None, impl: str = "pallas",
-                    bq: int = 128, bk: int = 128, interpret: bool = True):
+                    bq: int = 128, bk: int = 128, interpret: bool):
     """GQA flash attention. q: (B,S,Hq,hd); k,v: (B,S,K,hd), K | Hq."""
     q, k, v = _expand_gqa(q, k, v)
     if impl == "pallas":

@@ -12,7 +12,7 @@ from repro.kernels.mlstm_chunk.ref import mlstm_ref
 
 @functools.partial(jax.jit, static_argnames=("impl", "chunk", "interpret"))
 def mlstm_chunk(q, k, v, log_i, log_f, *, impl: str = "pallas",
-                chunk: int = 128, interpret: bool = True):
+                chunk: int = 128, interpret: bool):
     """q,k,v: (B, S, hd); gates (B, S). Returns h (B, S, hd) fp32."""
     if impl == "pallas":
         return mlstm_chunk_pallas(q, k, v, log_i, log_f, chunk=chunk,

@@ -12,7 +12,7 @@ from repro.kernels.rglru_scan.ref import rglru_scan_ref
 @functools.partial(jax.jit, static_argnames=("impl", "chunk", "bw",
                                              "interpret"))
 def rglru_scan(log_a, x, h0, *, impl: str = "pallas", chunk: int = 256,
-               bw: int = 128, interpret: bool = True):
+               bw: int = 128, interpret: bool):
     if impl == "pallas":
         return rglru_scan_pallas(log_a, x, h0, chunk=chunk, bw=bw,
                                  interpret=interpret)

@@ -11,12 +11,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-
-try:                                  # jax >= 0.6 exports it at top level
-    from jax import shard_map as _shard_map
-except ImportError:                   # jax 0.4/0.5
-    from jax.experimental.shard_map import shard_map as _shard_map
 
 from repro.models import sharding as SH
 from repro.models.sharding import constrain
@@ -205,7 +201,7 @@ def dist_decode_attention(q, k_cache, v_cache, k_new, v_new, pos):
 
     def bspec(*rest):
         return P(batch_ax, *rest)
-    out, kc, vc = _shard_map(
+    out, kc, vc = shard_map(
         body, mesh=mesh,
         in_specs=(bspec(None, None, None), bspec(seq_ax, None, None),
                   bspec(seq_ax, None, None), bspec(None, None, None),
@@ -393,7 +389,7 @@ def tp_mlp_forward(p, x, cfg):
     for w in ws[:-1]:
         in_specs.append(P(None, ax))
     in_specs.append(P(ax, None))
-    return _shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
+    return shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
                          out_specs=P(batch_ax, None, None))(x, *ws)
 
 
@@ -412,7 +408,7 @@ def tp_attn_out(out_heads, wo, cfg):
         return jax.lax.psum(y, ax)
 
     del n
-    return _shard_map(
+    return shard_map(
         body, mesh=mesh,
         in_specs=(P(batch_ax, None, ax, None), P(ax, None)),
         out_specs=P(batch_ax, None, None),

@@ -6,18 +6,20 @@ prefill workers fill real KV caches, the ring buffer hands the tensors to
 decode workers, decode workers run continuous batching with per-slot
 positions, and the SAME RapidController/PowerManager drive power and role
 decisions. Power caps scale a logical clock (hardware power knobs cannot be
-actuated from CPU), so the control loop sees the same dynamics end-to-end.
+actuated from JAX), so the control loop sees the same dynamics end-to-end.
 
 This is the mechanism-fidelity complement to the simulator: it proves the
 KV handoff, per-slot batching, drain-and-flip role moves, and controller
-integration on real tensors (CPU-sized models; TPU-sized via pjit configs).
+integration on real tensors. Every worker runs on JAX's default device:
+reduced configs on the CPU in the tests, published widths on one TPU
+(``launch/serve.py``, ``chip_smoke.py``).
 """
 from __future__ import annotations
 
 import dataclasses
 import time
 from collections import deque
-from typing import List, Optional
+from typing import Callable, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -57,6 +59,30 @@ def _cache_insert(family: str, dst, src, slot: int):
     return jax.tree_util.tree_map_with_path(ins, dst, src)
 
 
+def build_steps(lm: LM):
+    """The engine's jitted ``(prefill, decode)`` steps for ``lm``.
+
+    ``prefill(params, tokens (B, S), cache) -> (last logits (B, V), cache)``;
+    ``decode(params, tokens (B,), cache) -> (next (B,) int32, logits (B, V),
+    cache)``. Module level so a compile-only check can lower exactly what
+    the engine serves from parameter shapes, without building an engine.
+    """
+    cfg = lm.cfg
+
+    def prefill(p, toks, cache):
+        batch = {"tokens": toks}
+        if cfg.is_encoder_decoder:   # stubbed audio frontend embeddings
+            batch["enc_feats"] = jnp.zeros(
+                (toks.shape[0], cfg.encoder_seq, cfg.d_model), jnp.float32)
+        return lm.prefill(p, batch, cache)
+
+    def decode(p, tok, cache):
+        logits, cache = lm.decode_step(p, tok, cache)
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32), logits, cache
+
+    return jax.jit(prefill), jax.jit(decode)
+
+
 class Worker:
     def __init__(self, wid: int, role: str):
         self.wid = wid
@@ -76,10 +102,16 @@ class DisaggEngine:
                  ctrl_cfg: Optional[ControllerConfig] = None,
                  power: Optional[PowerModel] = None, seed: int = 0,
                  caps: Optional[List[float]] = None,
-                 time_scale: float = 1.0):
+                 time_scale: float = 1.0,
+                 on_decode: Optional[Callable] = None):
+        """``on_decode(active, logits)``, if given, is called after every
+        decode step with the worker's ``{slot: ServeRequest}`` (before
+        finished requests leave it) and the step's (decode_slots, V) logits
+        on the device."""
         self.cfg = cfg
         self.lm = LM(cfg)
-        self.params = self.lm.init(jax.random.key(seed), dtype=jnp.float32)
+        self.params = jax.jit(self.lm.init, static_argnums=1)(
+            jax.random.key(seed), jnp.float32)
         self.max_len = max_len
         self.decode_slots = decode_slots
         n = n_prefill + n_decode
@@ -100,20 +132,28 @@ class DisaggEngine:
         self.recent_ttft: deque = deque(maxlen=64)
         self.recent_tpot: deque = deque(maxlen=64)
 
+        self.on_decode = on_decode
         # jitted steps (shared across workers; params are shared)
-        def _pre(p, toks, cache):
-            batch = {"tokens": toks}
-            if cfg.is_encoder_decoder:   # stubbed audio frontend embeddings
-                batch["enc_feats"] = jnp.zeros(
-                    (toks.shape[0], cfg.encoder_seq, cfg.d_model), jnp.float32)
-            return self.lm.prefill(p, batch, cache)
-        self._prefill = jax.jit(_pre)
-        def _dec(p, tok, cache):
-            logits, cache = self.lm.decode_step(p, tok, cache)
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32), cache
-        self._decode = jax.jit(_dec)
+        self.prefill_step, self.decode_step = build_steps(self.lm)
 
     # ------------------------------------------------------------------
+    def warmup(self, prompt_len: int) -> dict:
+        """Compile the prefill step for ``(1, prompt_len)`` prompts and the
+        decode step for ``decode_slots`` by running each once on zeros.
+        Returns each first call's seconds: compile plus one execution."""
+        toks = jnp.zeros((1, prompt_len), jnp.int32)
+        cache = self.lm.init_cache(1, self.max_len, dtype=jnp.float32)
+        t0 = time.perf_counter()
+        jax.block_until_ready(self.prefill_step(self.params, toks, cache))
+        t1 = time.perf_counter()
+        cache = dict(self.lm.init_cache(self.decode_slots, self.max_len,
+                                        dtype=jnp.float32))
+        cache["pos"] = jnp.zeros((self.decode_slots,), jnp.int32)
+        tok = jnp.zeros((self.decode_slots,), jnp.int32)
+        t2 = time.perf_counter()
+        jax.block_until_ready(self.decode_step(self.params, tok, cache))
+        return {"prefill": t1 - t0, "decode": time.perf_counter() - t2}
+
     def submit(self, prompt: np.ndarray, out_tokens: int, now: float,
                ttft_slo=1.0, tpot_slo=0.04):
         rid = len(self.records)
@@ -134,7 +174,7 @@ class DisaggEngine:
         toks = jnp.asarray(req.tokens)[None, :]
         cache = self.lm.init_cache(1, self.max_len, dtype=jnp.float32)
         t0 = time.perf_counter()
-        logits, cache = self._prefill(self.params, toks, cache)
+        logits, cache = self.prefill_step(self.params, toks, cache)
         jax.block_until_ready(logits)
         dt = self._logical_dt(time.perf_counter() - t0, "prefill", w.wid)
         self.clock = max(self.clock, w.free_at) + dt
@@ -175,7 +215,8 @@ class DisaggEngine:
         cache = dict(w.cache)
         cache["pos"] = w.pos
         t0 = time.perf_counter()
-        nxt, cache = self._decode(self.params, jnp.asarray(tok), cache)
+        nxt, logits, cache = self.decode_step(self.params, jnp.asarray(tok),
+                                             cache)
         jax.block_until_ready(nxt)
         dt = self._logical_dt(time.perf_counter() - t0, "decode", w.wid)
         self.clock = max(self.clock, w.free_at) + dt
@@ -183,6 +224,8 @@ class DisaggEngine:
         self.recent_tpot.append(dt)
         w.pos = cache.pop("pos")
         w.cache = cache
+        if self.on_decode is not None:
+            self.on_decode(w.active, logits)
         done = []
         for slot, req in list(w.active.items()):
             req.generated.append(int(nxt[slot]))

@@ -26,7 +26,7 @@ def test_flash_attention(B, S, Hq, K, hd, window, dtype):
     q = jax.random.normal(ks[0], (B, S, Hq, hd), dtype)
     k = jax.random.normal(ks[1], (B, S, K, hd), dtype)
     v = jax.random.normal(ks[2], (B, S, K, hd), dtype)
-    out = flash_attention(q, k, v, window=window)
+    out = flash_attention(q, k, v, window=window, interpret=True)
     kk = jnp.repeat(k, Hq // K, 2)
     vv = jnp.repeat(v, Hq // K, 2)
     ref = flash_attention_ref(q.astype(jnp.float32), kk.astype(jnp.float32),
@@ -46,7 +46,7 @@ def test_decode_attention(B, S, Hq, K, hd, bs, pos):
     q = jax.random.normal(ks[0], (B, Hq, hd), jnp.float32)
     kc = jax.random.normal(ks[1], (B, S, K, hd), jnp.float32)
     vc = jax.random.normal(ks[2], (B, S, K, hd), jnp.float32)
-    out = decode_attention(q, kc, vc, pos, bs=bs)
+    out = decode_attention(q, kc, vc, pos, bs=bs, interpret=True)
     ref = decode_attention_ref(q, kc, vc, pos)
     assert float(jnp.max(jnp.abs(out - ref))) < 2e-5
 
@@ -62,7 +62,7 @@ def test_rglru_scan(B, S, W, chunk, bw):
     la = -jnp.abs(jax.random.normal(ks[0], (B, S, W))) * 0.2
     x = jax.random.normal(ks[1], (B, S, W))
     h0 = jax.random.normal(ks[2], (B, W))
-    out = rglru_scan(la, x, h0, chunk=chunk, bw=bw)
+    out = rglru_scan(la, x, h0, chunk=chunk, bw=bw, interpret=True)
     ref = rglru_scan_ref(la, x, h0)
     assert float(jnp.max(jnp.abs(out - ref))) < 1e-4
 
@@ -78,6 +78,6 @@ def test_mlstm_chunk(B, S, hd, chunk):
     v = jax.random.normal(ks[2], (B, S, hd))
     li = jax.random.normal(ks[3], (B, S)) * 0.5
     lf = jax.nn.log_sigmoid(jax.random.normal(ks[4], (B, S)) + 3.0)
-    out = mlstm_chunk(q, k, v, li, lf, chunk=chunk)
-    ref = mlstm_chunk(q, k, v, li, lf, impl="ref")
+    out = mlstm_chunk(q, k, v, li, lf, chunk=chunk, interpret=True)
+    ref = mlstm_chunk(q, k, v, li, lf, impl="ref", interpret=True)
     assert float(jnp.max(jnp.abs(out - ref))) < 2e-4

@@ -90,7 +90,7 @@ def test_rglru_random(B, S, W, seed):
     la = -jnp.abs(jax.random.normal(ks[0], (B, S, W))) * 0.3
     x = jax.random.normal(ks[1], (B, S, W))
     h0 = jax.random.normal(ks[2], (B, W))
-    out = rglru_scan(la, x, h0, chunk=64, bw=128)
+    out = rglru_scan(la, x, h0, chunk=64, bw=128, interpret=True)
     ref = rglru_scan_ref(la, x, h0)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-4, rtol=2e-4)

@@ -1,0 +1,134 @@
+"""Compile-only checks for one TPU v5e chip, against a described v5e:2x2
+topology: nothing runs and no chip is needed, only the TPU compiler.
+
+- The engine's jitted prefill and decode steps (``serving.engine.
+  build_steps``) at granite_3_8b's published widths, cut to
+  ``CHIP_LAYERS`` layers, under the one-chip traffic (``Traffic()``), must
+  compile and leave at least 2 GB of the chip's 16 GiB free.
+- Each Pallas kernel must be accepted by the TPU compiler at a real width.
+
+The topology is described in a fixture, never at import: only one process
+at a time may load the TPU library.
+"""
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.kernels.decode_attention.ops import decode_attention
+from repro.kernels.flash_attention.ops import flash_attention
+from repro.kernels.mlstm_chunk.ops import mlstm_chunk
+from repro.kernels.rglru_scan.ops import rglru_scan
+from repro.launch.serve import CHIP_LAYERS, Traffic, cut_depth
+from repro.models import LM
+from repro.serving.engine import build_steps
+
+HBM_BYTES = 16 * 2**30          # one TPU v5e
+HEADROOM_BYTES = 2 * 10**9
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    log_dir = os.environ.get("TPU_LOG_DIR")
+    os.environ["TPU_LOG_DIR"] = "disabled"
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means "cannot describe"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without one: keep the cache out of it
+    cache_was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", cache_was_on)
+    cc.reset_cache()
+    if log_dir is None:
+        os.environ.pop("TPU_LOG_DIR", None)
+    else:
+        os.environ["TPU_LOG_DIR"] = log_dir
+
+
+def _on(sharding, tree):
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        tree)
+
+
+def _nbytes(tree):
+    return sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(tree))
+
+
+def engine_step_bytes(sharding, n_layers: int, traffic: Traffic) -> dict:
+    """Compile the engine's steps for granite_3_8b cut to ``n_layers`` and
+    return the device bytes live at the peak of each phase."""
+    cfg = cut_depth(get_config("granite_3_8b"), n_layers)
+    lm = LM(cfg)
+    prefill, decode = build_steps(lm)
+    params = _on(sharding, jax.eval_shape(
+        lambda k: lm.init(k, jnp.float32), jax.random.key(0)))
+    cache1 = _on(sharding, jax.eval_shape(
+        lambda: lm.init_cache(1, traffic.max_len, dtype=jnp.float32)))
+    dcache = jax.eval_shape(
+        lambda: lm.init_cache(traffic.decode_slots, traffic.max_len,
+                              dtype=jnp.float32))
+    dcache["pos"] = jax.ShapeDtypeStruct((traffic.decode_slots,), jnp.int32)
+    dcache = _on(sharding, dcache)
+    toks = jax.ShapeDtypeStruct((1, traffic.prompt_len), jnp.int32,
+                                sharding=sharding)
+    tok = jax.ShapeDtypeStruct((traffic.decode_slots,), jnp.int32,
+                               sharding=sharding)
+    pre = prefill.lower(params, toks, cache1).compile().memory_analysis()
+    dec = decode.lower(params, tok, dcache).compile().memory_analysis()
+    # prefilled caches wait in the KV ring while every decode slot is busy
+    ring = max(traffic.requests - traffic.decode_slots, 0) * _nbytes(cache1)
+
+    def total(m):
+        return (m.argument_size_in_bytes + m.output_size_in_bytes +
+                m.temp_size_in_bytes)
+    return {"decode": total(dec) + ring,
+            # the decode worker's cache stays resident while prefill runs
+            "prefill": total(pre) + _nbytes(dcache) + ring}
+
+
+def test_engine_steps_fit_one_chip(one_chip):
+    peak = engine_step_bytes(one_chip, CHIP_LAYERS, Traffic())
+    for phase, nbytes in peak.items():
+        assert nbytes <= HBM_BYTES - HEADROOM_BYTES, (phase, nbytes)
+
+
+# Real widths: decode attention at granite_3_8b (K=8, hd=128, S=2048),
+# flash attention at granite_3_8b (32/8 heads x 128), the RG-LRU scan at
+# recurrentgemma_2b (W=2560), the mLSTM chunk at xlstm (hd 512, S=2048).
+KERNEL_CASES = {
+    "decode_attention": (decode_attention, [
+        ((8, 32, 128), jnp.float32), ((8, 2048, 8, 128), jnp.float32),
+        ((8, 2048, 8, 128), jnp.float32), ((), jnp.int32)]),
+    "flash_attention": (flash_attention, [
+        ((1, 2048, 32, 128), jnp.float32), ((1, 2048, 8, 128), jnp.float32),
+        ((1, 2048, 8, 128), jnp.float32)]),
+    "rglru_scan": (rglru_scan, [
+        ((2, 2048, 2560), jnp.float32), ((2, 2048, 2560), jnp.float32),
+        ((2, 2560), jnp.float32)]),
+    "mlstm_chunk": (mlstm_chunk, [
+        ((8, 2048, 512), jnp.float32), ((8, 2048, 512), jnp.float32),
+        ((8, 2048, 512), jnp.float32), ((8, 2048), jnp.float32),
+        ((8, 2048), jnp.float32)]),
+}
+
+
+@pytest.mark.parametrize("name", sorted(KERNEL_CASES))
+def test_kernel_compiles_for_tpu(one_chip, name):
+    fn, shapes = KERNEL_CASES[name]
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in shapes]
+    compiled = jax.jit(functools.partial(fn, interpret=False)).lower(
+        *args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
