@@ -13,6 +13,15 @@ KV handoff, per-slot batching, drain-and-flip role moves, and controller
 integration on real tensors. Every worker runs on JAX's default device:
 reduced configs on the CPU in the tests, published widths on one TPU
 (``launch/serve.py``, ``chip_smoke.py``).
+
+The engine's host work is named in the profiler's trace by spans
+(``jax.profiler.TraceAnnotation``, always on, about a microsecond each
+without a profiler): ``engine.prefill`` and ``engine.insert`` (arg
+``rid``), ``engine.decode`` around one decode iteration with
+``engine.decode.step`` (token vector, dispatch, wait) inside it,
+``engine.to_host`` around every device->host read, ``engine.on_decode``,
+``engine.ctrl`` and ``engine.summarize``. ``ServeRequest.token_t`` holds
+the host clock at which each generated token's value reached the host.
 """
 from __future__ import annotations
 
@@ -40,7 +49,21 @@ class ServeRequest:
     rec: RequestRecord
     tokens: np.ndarray               # prompt
     generated: list = dataclasses.field(default_factory=list)
+    token_t: list = dataclasses.field(default_factory=list)  # perf_counter
     slot: int = -1                   # decode slot index
+
+
+def _span(name: str, **args):
+    """The host span ``engine.<name>`` in the profiler's trace; ``args``
+    become the event's stats."""
+    return jax.profiler.TraceAnnotation(f"engine.{name}", **args)
+
+
+def _to_host(x, i: int) -> int:
+    """One device->host read: ``int(x[i])``, the eager slice included,
+    inside ``engine.to_host``."""
+    with _span("to_host"):
+        return int(x[i])
 
 
 def _cache_insert(family: str, dst, src, slot: int):
@@ -66,6 +89,8 @@ def build_steps(lm: LM):
     ``decode(params, tokens (B,), cache) -> (next (B,) int32, logits (B, V),
     cache)``. Module level so a compile-only check can lower exactly what
     the engine serves from parameter shapes, without building an engine.
+    The benchmark's trace reduction finds the steps by their module names,
+    ``jit_prefill`` and ``jit_decode``: keep the inner functions' names.
     """
     cfg = lm.cfg
 
@@ -171,19 +196,21 @@ class DisaggEngine:
         if not self.queue or self.ring.n_free == 0:
             return False
         req = self.queue.popleft()
-        toks = jnp.asarray(req.tokens)[None, :]
-        cache = self.lm.init_cache(1, self.max_len, dtype=jnp.float32)
-        t0 = time.perf_counter()
-        logits, cache = self.prefill_step(self.params, toks, cache)
-        jax.block_until_ready(logits)
-        dt = self._logical_dt(time.perf_counter() - t0, "prefill", w.wid)
-        self.clock = max(self.clock, w.free_at) + dt
-        w.free_at = self.clock
-        first = int(jnp.argmax(logits[0]))
-        req.rec.prefill_done = self.clock
-        self.recent_ttft.append(req.rec.ttft)
-        req.generated.append(first)
-        assert self.ring.try_put((req, cache, first)) is not None
+        with _span("prefill", rid=req.rec.rid):
+            toks = jnp.asarray(req.tokens)[None, :]
+            cache = self.lm.init_cache(1, self.max_len, dtype=jnp.float32)
+            t0 = time.perf_counter()
+            logits, cache = self.prefill_step(self.params, toks, cache)
+            jax.block_until_ready(logits)
+            dt = self._logical_dt(time.perf_counter() - t0, "prefill", w.wid)
+            self.clock = max(self.clock, w.free_at) + dt
+            w.free_at = self.clock
+            first = _to_host(jnp.argmax(logits, axis=-1), 0)
+            req.token_t.append(time.perf_counter())
+            req.rec.prefill_done = self.clock
+            self.recent_ttft.append(req.rec.ttft)
+            req.generated.append(first)
+            assert self.ring.try_put((req, cache, first)) is not None
         return True
 
     def _ensure_decode_state(self, w: Worker):
@@ -200,43 +227,48 @@ class DisaggEngine:
             slot = next(i for i in range(self.decode_slots)
                         if i not in {r.slot for r in w.active.values()})
             req.slot = slot
-            w.cache = _cache_insert(self.cfg.family, w.cache, cache, slot)
-            w.pos = w.pos.at[slot].set(len(req.tokens))
+            with _span("insert", rid=req.rec.rid):
+                w.cache = _cache_insert(self.cfg.family, w.cache, cache, slot)
+                w.pos = w.pos.at[slot].set(len(req.tokens))
             w.active[slot] = req
 
     def _do_decode_iter(self, w: Worker) -> bool:
-        self._admit(w)
-        if not w.active:
-            return False
-        # feed each slot its last token (inactive slots feed 0)
-        tok = np.zeros((self.decode_slots,), np.int32)
-        for slot, req in w.active.items():
-            tok[slot] = req.generated[-1]
-        cache = dict(w.cache)
-        cache["pos"] = w.pos
-        t0 = time.perf_counter()
-        nxt, logits, cache = self.decode_step(self.params, jnp.asarray(tok),
-                                             cache)
-        jax.block_until_ready(nxt)
-        dt = self._logical_dt(time.perf_counter() - t0, "decode", w.wid)
-        self.clock = max(self.clock, w.free_at) + dt
-        w.free_at = self.clock
-        self.recent_tpot.append(dt)
-        w.pos = cache.pop("pos")
-        w.cache = cache
-        if self.on_decode is not None:
-            self.on_decode(w.active, logits)
-        done = []
-        for slot, req in list(w.active.items()):
-            req.generated.append(int(nxt[slot]))
-            if len(req.generated) >= req.rec.output_tokens or \
-                    int(w.pos[slot]) >= self.max_len - 1:
-                req.rec.finish = self.clock
-                self.finished.append(req)
-                done.append(slot)
-        for slot in done:
-            del w.active[slot]
-        return True
+        with _span("decode"):
+            self._admit(w)
+            if not w.active:
+                return False
+            with _span("decode.step"):
+                # feed each slot its last token (inactive slots feed 0)
+                tok = np.zeros((self.decode_slots,), np.int32)
+                for slot, req in w.active.items():
+                    tok[slot] = req.generated[-1]
+                cache = dict(w.cache)
+                cache["pos"] = w.pos
+                t0 = time.perf_counter()
+                nxt, logits, cache = self.decode_step(
+                    self.params, jnp.asarray(tok), cache)
+                jax.block_until_ready(nxt)
+            dt = self._logical_dt(time.perf_counter() - t0, "decode", w.wid)
+            self.clock = max(self.clock, w.free_at) + dt
+            w.free_at = self.clock
+            self.recent_tpot.append(dt)
+            w.pos = cache.pop("pos")
+            w.cache = cache
+            if self.on_decode is not None:
+                with _span("on_decode"):
+                    self.on_decode(w.active, logits)
+            done = []
+            for slot, req in list(w.active.items()):
+                req.generated.append(_to_host(nxt, slot))
+                req.token_t.append(time.perf_counter())
+                if len(req.generated) >= req.rec.output_tokens or \
+                        _to_host(w.pos, slot) >= self.max_len - 1:
+                    req.rec.finish = self.clock
+                    self.finished.append(req)
+                    done.append(slot)
+            for slot in done:
+                del w.active[slot]
+            return True
 
     # ------------------------------------------------------------------
     def _ctrl_tick(self):
@@ -287,11 +319,13 @@ class DisaggEngine:
                     progressed |= self._do_prefill(w)
                 else:
                     progressed |= self._do_decode_iter(w)
-            self._ctrl_tick()
+            with _span("ctrl"):
+                self._ctrl_tick()
             if not progressed:
                 if all(r.finish is not None for r in self.records):
                     break
                 self.clock += 0.01
         dur = max((r.finish or self.clock) for r in self.records) \
             if self.records else self.clock
-        return summarize(self.records, dur, sum(self.pm.effective))
+        with _span("summarize"):
+            return summarize(self.records, dur, sum(self.pm.effective))

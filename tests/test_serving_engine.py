@@ -1,5 +1,6 @@
 """Real-compute disaggregated engine: KV handoff through the ring buffer,
 continuous batching with per-slot positions, exact token-level consistency."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -7,7 +8,7 @@ import pytest
 from repro.configs import get_config
 from repro.core.controller import ControllerConfig
 from repro.models import LM
-from repro.serving.engine import DisaggEngine
+from repro.serving.engine import DisaggEngine, build_steps
 
 
 @pytest.mark.parametrize("arch", ["qwen1_5_4b", "xlstm_350m",
@@ -71,3 +72,23 @@ def test_ring_backpressure(rng):
     assert ring.try_put("c") is None       # full -> backpressure
     assert ring.try_pull() == "a"          # pull frees a slot, FIFO order
     assert ring.try_put("c") is not None
+
+
+def test_step_module_names_are_stable():
+    """The benchmark's trace reduction finds the engine's steps on the
+    device by their module names."""
+    cfg = get_config("granite_3_8b").reduced()
+    lm = LM(cfg)
+    prefill, decode = build_steps(lm)
+    params = jax.eval_shape(lambda k: lm.init(k, jnp.float32),
+                            jax.random.key(0))
+    cache = jax.eval_shape(lambda: lm.init_cache(1, 32, dtype=jnp.float32))
+    dcache = dict(jax.eval_shape(
+        lambda: lm.init_cache(2, 32, dtype=jnp.float32)))
+    dcache["pos"] = jax.ShapeDtypeStruct((2,), jnp.int32)
+    toks = jax.ShapeDtypeStruct((1, 8), jnp.int32)
+    tok = jax.ShapeDtypeStruct((2,), jnp.int32)
+    assert "module @jit_prefill " in prefill.lower(params, toks,
+                                                   cache).as_text()
+    assert "module @jit_decode " in decode.lower(params, tok,
+                                                 dcache).as_text()
