@@ -1,5 +1,6 @@
-"""Shared neural-net layers: norms, RoPE, GQA attention (train/prefill/decode,
-causal + sliding-window), MLPs, and parameter initializers.
+"""Shared neural-net layers: norms, RoPE, dense projections, GQA attention
+(train/prefill/decode, causal + sliding-window), MLPs, and parameter
+initializers.
 
 All functions are pure; parameters are plain dict pytrees. Attention math is
 done in fp32 regardless of the activation dtype.
@@ -7,13 +8,14 @@ done in fp32 regardless of the activation dtype.
 from __future__ import annotations
 
 import math
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
+from repro.kernels.wstream_matmul.ops import wstream_matmul
 from repro.models import sharding as SH
 from repro.models.sharding import constrain
 
@@ -92,6 +94,85 @@ def sinusoidal_pos(positions, d_model: int, dtype):
     freqs = jnp.exp(-math.log(10000.0) * jnp.arange(half, dtype=jnp.float32) / (half - 1))
     ang = positions.astype(jnp.float32)[..., None] * freqs
     return jnp.concatenate([jnp.sin(ang), jnp.cos(ang)], axis=-1).astype(dtype)
+
+
+# ---------------------------------------------------------------------------
+# dense projection
+# ---------------------------------------------------------------------------
+
+class LayerWeight(NamedTuple):
+    """Layer ``layer`` of a stacked projection weight ``stack`` (L, K, N),
+    left unsliced so that ``proj`` can read it where it lies."""
+    stack: jax.Array
+    layer: jax.Array
+
+    @property
+    def shape(self):
+        return self.stack.shape[1:]
+
+    @property
+    def dtype(self):
+        return self.stack.dtype
+
+
+_PROJECTIONS = {"attn": ("wq", "wk", "wv", "wo"), "ffn": ("wi", "wg", "wo")}
+
+
+def layer_params(stacked, i):
+    """Layer ``i`` of per-layer params stacked on a leading axis (a dict, or
+    a tuple of them): each dense projection weight (a 3-D stack) as a
+    ``LayerWeight``, the rest sliced. Under sharding rules all is sliced."""
+    def take(tree):
+        return jax.tree.map(lambda a: a[i], tree)
+
+    if isinstance(stacked, tuple):
+        return tuple(layer_params(s, i) for s in stacked)
+    if SH.active():
+        return take(stacked)
+    out = {}
+    for key, sub in stacked.items():
+        names = _PROJECTIONS.get(key, ())
+        out[key] = {n: LayerWeight(a, i) if n in names and a.ndim == 3
+                    else take(a) for n, a in sub.items()} if names else take(sub)
+    return out
+
+
+def scan_layers(body, carry, xs):
+    """``jax.lax.scan(body, carry, (params, rest))`` over layers, for
+    inference: ``params`` are stacked per-layer params, and ``body`` gets
+    each layer's as ``layer_params`` gives them, so the projections read
+    their layer of each weight stack in place instead of a sliced copy."""
+    stacked, rest = xs
+    n = jax.tree.leaves(stacked)[0].shape[0]
+    return jax.lax.scan(
+        lambda c, ir: body(c, (layer_params(stacked, ir[0]), ir[1])),
+        carry, (jnp.arange(n), rest))
+
+
+def _weight(w):
+    if isinstance(w, LayerWeight):
+        return jax.lax.dynamic_index_in_dim(w.stack, w.layer, keepdims=False)
+    return w
+
+
+def proj(x, w):
+    """``x @ w`` for a dense projection weight ``w`` (K, N) or a
+    ``LayerWeight``.
+
+    Lowered for a TPU, a float32 ``w`` outside any sharding rules goes
+    through ``wstream_matmul``: the same default-precision matmul (operands
+    rounded to bf16, f32 accumulation) without the bf16 copy of ``w`` that
+    XLA writes to HBM on every call, and without a copy of the layer's
+    slice of a stack. Every other case, and every other platform, is the
+    plain ``x @ w``.
+    """
+    if w.dtype != jnp.float32 or len(w.shape) != 2 or SH.active():
+        return x @ _weight(w)
+    stack, layer = w if isinstance(w, LayerWeight) else (w, 0)
+    return jax.lax.platform_dependent(
+        x, stack,
+        tpu=lambda x, stack: wstream_matmul(x, stack, layer, interpret=False),
+        default=lambda x, stack: x @ _weight(w))
 
 
 # ---------------------------------------------------------------------------
@@ -265,9 +346,9 @@ def init_attention(key, cfg, dtype, cross: bool = False):
 def _project_qkv(p, x, cfg, positions, rope: bool):
     B = x.shape[0]
     S = x.shape[1]
-    q = x @ p["wq"]
-    k = x @ p["wk"]
-    v = x @ p["wv"]
+    q = proj(x, p["wq"])
+    k = proj(x, p["wk"])
+    v = proj(x, p["wv"])
     if "bq" in p:
         q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
     q = q.reshape(B, S, cfg.n_heads, cfg.head_dim)
@@ -294,7 +375,7 @@ def attn_forward(p, x, cfg, *, window=None, causal=True):
     out = constrain(out, "batch", None, "heads", None)
     if _tp_axis_ok(cfg.n_heads, "heads"):
         return tp_attn_out(out, p["wo"], cfg)
-    return out.reshape(x.shape[0], S, cfg.q_dim) @ p["wo"]
+    return proj(out.reshape(x.shape[0], S, cfg.q_dim), p["wo"])
 
 
 def attn_prefill(p, x, cfg, k_cache, v_cache, *, window=None):
@@ -305,7 +386,7 @@ def attn_prefill(p, x, cfg, k_cache, v_cache, *, window=None):
     out = gqa_attention(q, k, v, causal=True, window=window)
     k_cache = cache_fill_prefill(k_cache, k, window=window)
     v_cache = cache_fill_prefill(v_cache, v, window=window)
-    out = out.reshape(x.shape[0], S, cfg.q_dim) @ p["wo"]
+    out = proj(out.reshape(x.shape[0], S, cfg.q_dim), p["wo"])
     return out, k_cache, v_cache
 
 
@@ -329,7 +410,7 @@ def attn_decode(p, x, cfg, k_cache, v_cache, pos, *, window=None):
     k_cache = constrain(k_cache, "kv_batch", "kv_seq", None, None)
     v_cache = constrain(v_cache, "kv_batch", "kv_seq", None, None)
     out = decode_attention(q, k_cache, v_cache, pos, window=window)
-    out = out.reshape(x.shape[0], 1, cfg.q_dim) @ p["wo"]
+    out = proj(out.reshape(x.shape[0], 1, cfg.q_dim), p["wo"])
     return out, k_cache, v_cache
 
 
@@ -439,8 +520,8 @@ def mlp_forward(p, x, cfg):
     if _tp_axis_ok(p["wi"].shape[-1]):
         return tp_mlp_forward(p, x, cfg)
     if "wg" in p:
-        h = jax.nn.silu(x @ p["wg"]) * (x @ p["wi"])
+        h = jax.nn.silu(proj(x, p["wg"])) * proj(x, p["wi"])
     else:
-        h = jax.nn.gelu(x @ p["wi"])
+        h = jax.nn.gelu(proj(x, p["wi"]))
     h = constrain(h, "batch", None, "d_ff")
-    return h @ p["wo"]
+    return proj(h, p["wo"])

@@ -242,7 +242,8 @@ def _run_stack(params, x, cfg, mode, cache, remat=False):
         caches = jax.tree.map(
             lambda a: jnp.broadcast_to(a, (G, *a.shape)), tuple(
                 _slot_cache(cfg, k, x.shape[0], 1, x.dtype, None) for k in pat))
-    x, new_slots = jax.lax.scan(body, x, (params["slots"], caches))
+    scan = jax.lax.scan if mode == "train" else L.scan_layers
+    x, new_slots = scan(body, x, (params["slots"], caches))
     new_rest = []
     rest_caches = cache["rest"] if cache is not None else [None] * len(rest)
     for j, kind in enumerate(rest):

@@ -136,7 +136,7 @@ def _run_stack(params, x, cfg, mode, cache=None, window=None, remat=False):
     pos = cache["pos"]
     if remat and mode == "prefill":
         body = jax.checkpoint(body, prevent_cse=False)
-    (x, aux, _), new_slots = jax.lax.scan(
+    (x, aux, _), new_slots = L.scan_layers(
         body, (x, jnp.zeros((), jnp.float32), pos),
         (params["slots"], cache["slots"]))
     return x, aux, new_slots
@@ -153,7 +153,7 @@ def _embed(params, tokens):
 
 def _logits(params, x, cfg):
     x = L.apply_norm(x, params["final_norm"], cfg.norm, cfg.norm_eps)
-    logits = x @ params["unembed"]
+    logits = L.proj(x, params["unembed"])
     return constrain(logits, "batch", None, "vocab")
 
 

@@ -108,7 +108,7 @@ def _dec_stack(params, cfg, x, mode, cache, enc_out=None, window=None,
         if mode == "train":
             p = xs
         else:
-            p, kc, vc, ck, cv = xs
+            p, (kc, vc, ck, cv) = xs
         h = L.apply_norm(x, p["ln1"], cfg.norm, cfg.norm_eps)
         if mode == "train":
             a = L.attn_forward(p["attn"], h, cfg, window=window)
@@ -137,8 +137,8 @@ def _dec_stack(params, cfg, x, mode, cache, enc_out=None, window=None,
             body = jax.checkpoint(body, prevent_cse=False)
         x, _ = jax.lax.scan(body, x, params["dec"])
         return x, None
-    xs = (params["dec"], cache["k"], cache["v"], cache["ck"], cache["cv"])
-    x, new = jax.lax.scan(body, x, xs)
+    xs = (params["dec"], (cache["k"], cache["v"], cache["ck"], cache["cv"]))
+    x, new = L.scan_layers(body, x, xs)
     return x, new
 
 

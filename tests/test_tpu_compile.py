@@ -6,12 +6,16 @@ topology: nothing runs and no chip is needed, only the TPU compiler.
   ``CHIP_LAYERS`` layers, under the one-chip traffic (``Traffic()``), must
   compile and leave at least 2 GB of the chip's 16 GiB free.
 - Each Pallas kernel must be accepted by the TPU compiler at a real width.
+- Both steps must read every f32 projection weight in place through
+  ``wstream_matmul``: no bf16 copy of a weight, no copy of a layer's slice.
 
 The topology is described in a fixture, never at import: only one process
 at a time may load the TPU library.
 """
+import collections
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -23,6 +27,7 @@ from repro.kernels.decode_attention.ops import decode_attention
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.mlstm_chunk.ops import mlstm_chunk
 from repro.kernels.rglru_scan.ops import rglru_scan
+from repro.kernels.wstream_matmul.ops import wstream_matmul
 from repro.launch.serve import CHIP_LAYERS, Traffic, cut_depth
 from repro.models import LM
 from repro.serving.engine import build_steps
@@ -66,9 +71,10 @@ def _nbytes(tree):
     return sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(tree))
 
 
-def engine_step_bytes(sharding, n_layers: int, traffic: Traffic) -> dict:
-    """Compile the engine's steps for granite_3_8b cut to ``n_layers`` and
-    return the device bytes live at the peak of each phase."""
+def compiled_steps(sharding, n_layers: int, traffic: Traffic):
+    """Compile the engine's steps for granite_3_8b cut to ``n_layers``:
+    ``(params, cache1, dcache, compiled prefill, compiled decode)``, the
+    first three as shapes."""
     cfg = cut_depth(get_config("granite_3_8b"), n_layers)
     lm = LM(cfg)
     prefill, decode = build_steps(lm)
@@ -85,8 +91,16 @@ def engine_step_bytes(sharding, n_layers: int, traffic: Traffic) -> dict:
                                 sharding=sharding)
     tok = jax.ShapeDtypeStruct((traffic.decode_slots,), jnp.int32,
                                sharding=sharding)
-    pre = prefill.lower(params, toks, cache1).compile().memory_analysis()
-    dec = decode.lower(params, tok, dcache).compile().memory_analysis()
+    return (params, cache1, dcache,
+            prefill.lower(params, toks, cache1).compile(),
+            decode.lower(params, tok, dcache).compile())
+
+
+def engine_step_bytes(sharding, n_layers: int, traffic: Traffic) -> dict:
+    """Compile the engine's steps for granite_3_8b cut to ``n_layers`` and
+    return the device bytes live at the peak of each phase."""
+    _, cache1, dcache, pre, dec = compiled_steps(sharding, n_layers, traffic)
+    pre, dec = pre.memory_analysis(), dec.memory_analysis()
     # prefilled caches wait in the KV ring while every decode slot is busy
     ring = max(traffic.requests - traffic.decode_slots, 0) * _nbytes(cache1)
 
@@ -106,7 +120,9 @@ def test_engine_steps_fit_one_chip(one_chip):
 
 # Real widths: decode attention at granite_3_8b (K=8, hd=128, S=2048),
 # flash attention at granite_3_8b (32/8 heads x 128), the RG-LRU scan at
-# recurrentgemma_2b (W=2560), the mLSTM chunk at xlstm (hd 512, S=2048).
+# recurrentgemma_2b (W=2560), the mLSTM chunk at xlstm (hd 512, S=2048),
+# the weight-streaming matmul at granite_3_8b's MLP (decode rows) and
+# unembedding (prefill rows, vocabulary 49155).
 KERNEL_CASES = {
     "decode_attention": (decode_attention, [
         ((8, 32, 128), jnp.float32), ((8, 2048, 8, 128), jnp.float32),
@@ -117,6 +133,10 @@ KERNEL_CASES = {
     "rglru_scan": (rglru_scan, [
         ((2, 2048, 2560), jnp.float32), ((2, 2048, 2560), jnp.float32),
         ((2, 2560), jnp.float32)]),
+    "wstream_matmul": (wstream_matmul, [
+        ((8, 4096), jnp.float32), ((4096, 12800), jnp.float32)]),
+    "wstream_matmul_ragged": (wstream_matmul, [
+        ((768, 4096), jnp.float32), ((4096, 49155), jnp.float32)]),
     "mlstm_chunk": (mlstm_chunk, [
         ((8, 2048, 512), jnp.float32), ((8, 2048, 512), jnp.float32),
         ((8, 2048, 512), jnp.float32), ((8, 2048), jnp.float32),
@@ -132,3 +152,46 @@ def test_kernel_compiles_for_tpu(one_chip, name):
     compiled = jax.jit(functools.partial(fn, interpret=False)).lower(
         *args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# ``%name = f32[4096,12800]{1,0:T(8,128)} opcode(`` in the compiled text
+_INSTR = re.compile(r"%\S+ = (f32|bf16)\[([\d,]*)\]\S* ([\w-]+)\(")
+# the weight operand of a kernel call, the last of its operand layouts
+_KERNEL_W = re.compile(r'custom_call_target="tpu_custom_call", '
+                       r'operand_layout_constraints=\{.*f32\[([\d,]+)\]'
+                       r'\{[^}]*\}\}')
+
+
+def test_steps_stream_weights_in_place(one_chip):
+    """Every weight of ``jit_decode`` and ``jit_prefill`` is read where it
+    lies: an array of a weight's shape (a layer, a stack, either
+    orientation, f32 or bf16) comes only from a parameter, a loop-state
+    element or a bitcast, so no weight is converted to bf16 and no layer's
+    slice is copied. One ``wstream_matmul`` call reads each of the layer
+    body's seven projection stacks, and one the unembedding."""
+    params, *_, pre, dec = compiled_steps(one_chip, CHIP_LAYERS, Traffic())
+    weights = set()
+    for leaf in jax.tree.leaves(params):
+        for shape in (leaf.shape, leaf.shape[1:]):
+            if len(shape) >= 2 and leaf.size >= 2**20:
+                weights.add(shape)
+                weights.add((*shape[:-2], shape[-1], shape[-2]))
+    stacks = collections.Counter(
+        tuple(leaf.shape) for part in ("attn", "ffn")
+        for slot in params["slots"] for leaf in slot[part].values())
+    assert sum(stacks.values()) == 7
+    K, N = params["unembed"].shape
+    for step in (pre, dec):
+        text = step.as_text()
+        for dtype, dims, op in _INSTR.findall(text):
+            shape = tuple(int(d) for d in dims.split(",") if d)
+            while shape[:1] == (1,):
+                shape = shape[1:]
+            assert shape not in weights or op in (
+                "parameter", "get-tuple-element", "bitcast"), (
+                dtype, shape, op)
+        read = collections.Counter(
+            tuple(int(d) for d in m.split(","))
+            for m in _KERNEL_W.findall(text))
+        unembed = read.pop((1, K, N), 0) + read.pop((1, N, K), 0)
+        assert unembed == 1 and read == stacks, (read, stacks)
