@@ -30,20 +30,26 @@ class ModelConfig:
     qk_norm: bool = False
     rope_theta: float = 10_000.0
     window: Optional[int] = None    # sliding-window size (None = full causal)
+    # per-layer token mixer of the decoder stack: "attn" or "conv" (gated
+    # short convolution of width conv_width); () -> every layer "attn"
+    layer_types: Tuple[str, ...] = ()
     # MoE
     n_experts: int = 0
     top_k: int = 0
-    capacity_factor: float = 1.25
     shared_expert: bool = False     # llama4-style shared FFN beside routed experts
-    moe_group_size: int = 512       # gshard dispatch group size (tokens)
     moe_every: int = 1              # every k-th layer is MoE (llama4: 2)
     d_ff_dense: int = 0             # FFN width of interleaved dense layers (0 -> d_ff)
+    n_dense_layers: int = 0         # leading layers with a dense FFN, then MoE
+    router: str = "softmax"         # "softmax" | "sigmoid" (scores; a learned
+                                    # bias picks the top-k, never weighs them)
+    experts_held: Tuple[int, ...] = ()  # (lo, hi): experts lo..hi-1 of n_experts
+                                        # live here (expert parallel); () -> all
     # MLP variant
     mlp: str = "swiglu"             # "swiglu" | "gelu"
     # hybrid / ssm structure
     attn_pattern: Tuple[str, ...] = ()   # e.g. ("rec","rec","attn"); repeats over layers
     slstm_every: int = 0            # xlstm: every k-th layer is sLSTM (0 = none)
-    conv_width: int = 4             # RG-LRU temporal conv width
+    conv_width: int = 4             # RG-LRU temporal / short conv width
     lru_width: int = 0              # RG-LRU recurrence width (0 -> d_model)
     # encoder-decoder (audio)
     is_encoder_decoder: bool = False
@@ -59,6 +65,7 @@ class ModelConfig:
         assert self.family in FAMILIES, self.family
         if self.head_dim == 0:
             object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
+        assert not self.layer_types or len(self.layer_types) == self.n_layers
         assert self.n_heads % self.n_kv_heads == 0 or self.n_kv_heads > self.n_heads is False
 
     # ---- derived quantities -------------------------------------------------
@@ -69,6 +76,11 @@ class ModelConfig:
     @property
     def kv_dim(self) -> int:
         return self.n_kv_heads * self.head_dim
+
+    @property
+    def expert_range(self) -> Tuple[int, int]:
+        """The experts held here, ``[lo, hi)`` of ``n_experts``."""
+        return tuple(self.experts_held) or (0, self.n_experts)
 
     def layer_kinds(self) -> Tuple[str, ...]:
         """Per-layer block kind for the decoder stack."""
@@ -83,7 +95,7 @@ class ModelConfig:
         if self.family == "hybrid":
             pat = self.attn_pattern or ("rec", "rec", "attn")
             return tuple(pat[i % len(pat)] for i in range(self.n_layers))
-        return tuple("attn" for _ in range(self.n_layers))
+        return self.layer_types or tuple("attn" for _ in range(self.n_layers))
 
     def param_count(self) -> int:
         """Analytic parameter count (embedding included once if tied)."""
@@ -101,6 +113,9 @@ class ModelConfig:
                 total += D * (qd + 2 * kvd) + qd * D
                 if self.qkv_bias:
                     total += qd + 2 * kvd
+                total += self._ffn_params(fkind)
+            elif kind == "conv":
+                total += D * 3 * D + self.conv_width * D + D * D
                 total += self._ffn_params(fkind)
             elif kind == "mlstm":
                 # xlstm mLSTM block: up-proj 2x, q/k/v proj in inner dim, gates, out
@@ -127,8 +142,10 @@ class ModelConfig:
         """Per-layer FFN kind: "moe" or "dense" (interleaving per moe_every)."""
         if not self.n_experts:
             return tuple("dense" for _ in range(self.n_layers))
+        lead = self.n_dense_layers
         return tuple(
-            "moe" if (i % self.moe_every == self.moe_every - 1) else "dense"
+            "moe" if i >= lead and (i - lead) % self.moe_every
+            == self.moe_every - 1 else "dense"
             for i in range(self.n_layers)
         )
 
@@ -140,6 +157,8 @@ class ModelConfig:
         if self.n_experts and kind == "moe":
             per = mult * D * F
             total = self.n_experts * per + D * self.n_experts  # + router
+            if self.router == "sigmoid":
+                total += self.n_experts                        # + selection bias
             if self.shared_expert:
                 total += per
             return total
@@ -168,11 +187,16 @@ class ModelConfig:
             head_dim=64,
             d_ff=512 if self.d_ff else 0,
             vocab_size=512,
-            moe_group_size=64,
         )
         if self.n_experts:
             changes["n_experts"] = 4
             changes["top_k"] = min(self.top_k, 2)
+        if self.layer_types:
+            # one leading layer, then one slot of each mixer kind
+            lead = self.layer_types[: min(self.n_dense_layers, 1)]
+            rest = tuple(dict.fromkeys(self.layer_types[self.n_dense_layers:]))
+            changes.update(layer_types=lead + rest, n_layers=len(lead + rest),
+                           n_dense_layers=len(lead))
         if self.is_encoder_decoder:
             changes["n_encoder_layers"] = 2
             changes["encoder_seq"] = 16
@@ -217,6 +241,7 @@ ARCH_IDS = (
     "recurrentgemma_2b",
     "phi3_5_moe",
     "chameleon_34b",
+    "lfm2_24b_a2b",
 )
 
 # CLI-facing aliases (the assignment spells them with dots/dashes)
@@ -232,6 +257,7 @@ ARCH_ALIASES = {
     "phi3.5-moe-42b-a6.6b": "phi3_5_moe",
     "chameleon-34b": "chameleon_34b",
     "llama3.1-8b": "llama31_8b",
+    "lfm2-24b-a2b": "lfm2_24b_a2b",
 }
 
 

@@ -4,8 +4,10 @@
 Serves seeded requests through the real-compute disaggregated engine
 (prefill worker -> KV ring -> decode worker, RAPID controller on) on JAX's
 default device. Without ``--smoke`` the model keeps its published widths,
-and ``--layers N`` keeps its first N layers and changes nothing else: that
-is how a model too deep for one chip is served on one. ``--smoke`` serves
+and ``--layers N`` serves one chip's share of it: its first N layers and,
+of a MoE model, the first of ``EXPERT_SHARDS`` expert shards of each MoE
+layer; no width changes. That is how a model too large for one chip is
+served on one. ``--smoke`` serves
 the reduced 2-layer, width-256 config with small traffic, for the CPU.
 Weights and prompts are random from ``--seed``.
 
@@ -50,21 +52,46 @@ SMOKE_TRAFFIC = Traffic(requests=16, prompt_len=24, out_tokens=12,
 # compiled steps to that budget.
 CHIP_LAYERS = 8
 
+# A MoE model's experts are split over this many chips (expert parallel;
+# the rest replicated), so one chip holds a contiguous quarter of each MoE
+# layer's experts: lfm2_24b_a2b's experts 0-15 of 64.
+EXPERT_SHARDS = 4
+
 
 def cut_depth(cfg: ModelConfig, n_layers: int) -> ModelConfig:
     """``cfg`` with only its first ``n_layers`` layers; widths unchanged."""
-    if not 1 <= n_layers <= cfg.n_layers:
-        raise ValueError(f"{cfg.name} has {cfg.n_layers} layers; "
-                         f"cannot serve {n_layers}")
-    return dataclasses.replace(cfg, n_layers=n_layers)
+    if not cfg.n_dense_layers < n_layers <= cfg.n_layers:
+        raise ValueError(f"{cfg.name} has {cfg.n_layers} layers, the first "
+                         f"{cfg.n_dense_layers} dense; cannot serve "
+                         f"{n_layers}")
+    return dataclasses.replace(cfg, n_layers=n_layers,
+                               layer_types=cfg.layer_types[:n_layers])
+
+
+def cut_experts(cfg: ModelConfig, shards: int) -> ModelConfig:
+    """``cfg`` holding the first of ``shards`` equal, contiguous shards of
+    each MoE layer's experts; routing still runs over all of them. A model
+    without experts is returned as it is."""
+    if not cfg.n_experts:
+        return cfg
+    if cfg.n_experts % shards:
+        raise ValueError(f"{cfg.name}: {cfg.n_experts} experts do not split "
+                         f"into {shards} shards")
+    return dataclasses.replace(cfg,
+                               experts_held=(0, cfg.n_experts // shards))
 
 
 def describe(cfg: ModelConfig, published_layers: int) -> str:
     """One line naming ``cfg``'s depth cut and the widths it keeps."""
+    kinds = cfg.layer_kinds()
+    mixers = ", ".join(f"{kinds.count(k)} {k}" for k in dict.fromkeys(kinds))
+    lo, hi = cfg.expert_range
+    moe = (f", experts {lo}-{hi - 1} of {cfg.n_experts} held, top-"
+           f"{cfg.top_k}" if cfg.n_experts else "")
     return (f"{cfg.name}: {cfg.n_layers} of {published_layers} layers "
-            f"(depth cut only), d_model {cfg.d_model}, heads "
+            f"({mixers}), d_model {cfg.d_model}, heads "
             f"{cfg.n_heads}/{cfg.n_kv_heads} x {cfg.head_dim}, d_ff "
-            f"{cfg.d_ff}, vocab {cfg.vocab_size}")
+            f"{cfg.d_ff}, vocab {cfg.vocab_size}{moe}")
 
 
 @dataclasses.dataclass
@@ -142,7 +169,7 @@ def main():
     else:
         full = cfg.n_layers
         if args.layers is not None:
-            cfg = cut_depth(cfg, args.layers)
+            cfg = cut_experts(cut_depth(cfg, args.layers), EXPERT_SHARDS)
         traffic = Traffic()
         print(f"[serve] {describe(cfg, full)}")
     if args.requests is not None:

@@ -1,5 +1,6 @@
-"""Shared neural-net layers: norms, RoPE, dense projections, GQA attention
-(train/prefill/decode, causal + sliding-window), MLPs, and parameter
+"""Shared neural-net layers: norms, RoPE, dense and grouped expert
+projections, GQA attention (train/prefill/decode, causal +
+sliding-window), gated short convolutions, MLPs, and parameter
 initializers.
 
 All functions are pure; parameters are plain dict pytrees. Attention math is
@@ -15,6 +16,7 @@ import jax.numpy as jnp
 from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
+from repro.kernels.moe_gmm.ops import moe_gmm, plain_ffn
 from repro.kernels.wstream_matmul.ops import wstream_matmul
 from repro.models import sharding as SH
 from repro.models.sharding import constrain
@@ -102,7 +104,8 @@ def sinusoidal_pos(positions, d_model: int, dtype):
 
 class LayerWeight(NamedTuple):
     """Layer ``layer`` of a stacked projection weight ``stack`` (L, K, N),
-    left unsliced so that ``proj`` can read it where it lies."""
+    or of an expert stack (L, E, K, N), left unsliced so that ``proj`` or
+    ``expert_ffn`` can read it where it lies."""
     stack: jax.Array
     layer: jax.Array
 
@@ -115,13 +118,15 @@ class LayerWeight(NamedTuple):
         return self.stack.dtype
 
 
-_PROJECTIONS = {"attn": ("wq", "wk", "wv", "wo"), "ffn": ("wi", "wg", "wo")}
+_PROJECTIONS = {"attn": ("wq", "wk", "wv", "wo"), "ffn": ("wi", "wg", "wo"),
+                "conv": ("w_in", "w_out")}
 
 
 def layer_params(stacked, i):
     """Layer ``i`` of per-layer params stacked on a leading axis (a dict, or
-    a tuple of them): each dense projection weight (a 3-D stack) as a
-    ``LayerWeight``, the rest sliced. Under sharding rules all is sliced."""
+    a tuple of them): each projection weight (a 3-D stack, or a 4-D stack
+    of experts) as a ``LayerWeight``, the rest sliced. Under sharding rules
+    all is sliced."""
     def take(tree):
         return jax.tree.map(lambda a: a[i], tree)
 
@@ -132,7 +137,7 @@ def layer_params(stacked, i):
     out = {}
     for key, sub in stacked.items():
         names = _PROJECTIONS.get(key, ())
-        out[key] = {n: LayerWeight(a, i) if n in names and a.ndim == 3
+        out[key] = {n: LayerWeight(a, i) if n in names and a.ndim in (3, 4)
                     else take(a) for n, a in sub.items()} if names else take(sub)
     return out
 
@@ -155,9 +160,10 @@ def _weight(w):
     return w
 
 
-def proj(x, w):
+def proj(x, w, *, transposed: bool = False):
     """``x @ w`` for a dense projection weight ``w`` (K, N) or a
-    ``LayerWeight``.
+    ``LayerWeight``; with ``transposed``, ``x @ w.T`` for ``w`` (N, K), such
+    as an embedding table read as the unembedding.
 
     Lowered for a TPU, a float32 ``w`` outside any sharding rules goes
     through ``wstream_matmul``: the same default-precision matmul (operands
@@ -166,13 +172,41 @@ def proj(x, w):
     slice of a stack. Every other case, and every other platform, is the
     plain ``x @ w``.
     """
+    def plain(x):
+        wv = _weight(w)
+        return x @ (wv.T if transposed else wv)
+
     if w.dtype != jnp.float32 or len(w.shape) != 2 or SH.active():
-        return x @ _weight(w)
+        return plain(x)
     stack, layer = w if isinstance(w, LayerWeight) else (w, 0)
     return jax.lax.platform_dependent(
         x, stack,
-        tpu=lambda x, stack: wstream_matmul(x, stack, layer, interpret=False),
-        default=lambda x, stack: x @ _weight(w))
+        tpu=lambda x, stack: wstream_matmul(x, stack, layer, interpret=False,
+                                            transposed=transposed),
+        default=lambda x, stack: plain(x))
+
+
+def expert_ffn(x, wg, wi, wo, offsets):
+    """Grouped SwiGLU experts: ``(silu(x[r] @ wg[e]) * (x[r] @ wi[e])) @
+    wo[e]`` for the rows ``r`` of expert ``e``, ``[offsets[e], offsets[e +
+    1])``, for expert stacks (E, K, F), (E, K, F), (E, F, K) or
+    ``LayerWeight``s of them. Rows past ``offsets[E]`` are undefined.
+
+    Lowered for a TPU, float32 weights outside any sharding rules go
+    through ``moe_gmm``, which streams each expert's f32 weights through
+    VMEM as ``proj`` does; every other case is the plain grouped matmuls.
+    """
+    if isinstance(wg, LayerWeight):
+        layer, stacks = wg.layer, (wg.stack, wi.stack, wo.stack)
+    else:
+        layer, stacks = 0, (wg[None], wi[None], wo[None])
+    if wg.dtype != jnp.float32 or SH.active():
+        return plain_ffn(x, *stacks, offsets, layer)
+    return jax.lax.platform_dependent(
+        x, *stacks, offsets,
+        tpu=lambda x, g, i, o, off: moe_gmm(x, g, i, o, off, layer,
+                                            interpret=False),
+        default=lambda x, g, i, o, off: plain_ffn(x, g, i, o, off, layer))
 
 
 # ---------------------------------------------------------------------------
@@ -427,6 +461,47 @@ def cross_attn_apply(p, x, cfg, k, v):
     q = (x @ p["wq"]).reshape(B, S, cfg.n_heads, cfg.head_dim)
     out = gqa_attention(q, k, v, causal=False)
     return out.reshape(B, S, cfg.q_dim) @ p["wo"]
+
+
+# ---------------------------------------------------------------------------
+# gated short convolution (LFM2)
+# ---------------------------------------------------------------------------
+
+def init_conv(key, cfg, dtype):
+    D, W = cfg.d_model, cfg.conv_width
+    ks = jax.random.split(key, 3)
+    return {"w_in": dense_init(ks[0], (D, 3 * D), dtype),
+            "w_conv": dense_init(ks[1], (W, D), dtype,
+                                 scale=1.0 / math.sqrt(W)),
+            "w_out": dense_init(ks[2], (D, D), dtype)}
+
+
+def _conv_mix(p, x, z_before):
+    """The block's output for ``x`` (B, S, D), given the ``W - 1`` values
+    of z before it (B, W - 1, D); also the last ``W - 1`` values of z."""
+    b, c, u = jnp.split(proj(x, p["w_in"]), 3, axis=-1)
+    z = jnp.concatenate([z_before.astype(x.dtype), b * u], axis=1)
+    w = p["w_conv"].astype(x.dtype)
+    W, S = w.shape[0], x.shape[1]
+    y = sum(w[j] * z[:, j:j + S] for j in range(W))
+    return proj(c * y, p["w_out"]), z[:, S:]
+
+
+def conv_prefill(p, x, cfg):
+    """Gated short convolution over a whole sequence ``x`` (B, S, D):
+    ``[b, c, u] = x @ W_in``, ``z = b * u``, ``y_t = sum_j w[j] *
+    z_{t-W+1+j}`` (z is 0 before the first position), out ``(c * y) @
+    W_out``. Returns (out, state): the state is the last ``W - 1`` values
+    of z (B, W - 1, D), zeros where the sequence is shorter."""
+    B, _, D = x.shape
+    return _conv_mix(p, x, jnp.zeros((B, cfg.conv_width - 1, D), x.dtype))
+
+
+def conv_decode(p, x, state):
+    """One token ``x`` (B, 1, D) from the state ``conv_prefill`` or the
+    last step left; returns (out, the state rolled by one)."""
+    out, z = _conv_mix(p, x, state)
+    return out, z.astype(state.dtype)
 
 
 # ---------------------------------------------------------------------------

@@ -17,7 +17,9 @@ reduced configs on the CPU in the tests, published widths on one TPU
 The engine's host work is named in the profiler's trace by spans
 (``jax.profiler.TraceAnnotation``, always on, about a microsecond each
 without a profiler): ``engine.prefill`` and ``engine.insert`` (arg
-``rid``), ``engine.decode`` around one decode iteration with
+``rid``; the insert also ``state_bytes``, the bytes of the prefilled
+state handed to the slot: KV and any convolution state),
+``engine.decode`` around one decode iteration with
 ``engine.decode.step`` (token vector, dispatch, wait) inside it,
 ``engine.to_host`` around every device->host read, ``engine.on_decode``,
 ``engine.ctrl`` and ``engine.summarize``. ``ServeRequest.token_t`` holds
@@ -66,9 +68,16 @@ def _to_host(x, i: int) -> int:
         return int(x[i])
 
 
+def _state_bytes(cache) -> int:
+    """Bytes of a prefilled cache's state, its position aside."""
+    return sum(a.nbytes for k, v in cache.items() if k != "pos"
+               for a in jax.tree.leaves(v))
+
+
 def _cache_insert(family: str, dst, src, slot: int):
     """Insert a batch-1 prefilled cache into slot ``slot`` of a batched
-    decode cache. Batch dim is 1 for stacked leaves, 0 for hybrid 'rest'."""
+    decode cache: every stacked leaf (attention k/v, convolution state)
+    has its batch dim at 1, hybrid 'rest' leaves at 0."""
     dst = dict(dst)
     src = dict(src)
     dst.pop("pos", None)
@@ -227,7 +236,8 @@ class DisaggEngine:
             slot = next(i for i in range(self.decode_slots)
                         if i not in {r.slot for r in w.active.values()})
             req.slot = slot
-            with _span("insert", rid=req.rec.rid):
+            with _span("insert", rid=req.rec.rid,
+                       state_bytes=_state_bytes(cache)):
                 w.cache = _cache_insert(self.cfg.family, w.cache, cache, slot)
                 w.pos = w.pos.at[slot].set(len(req.tokens))
             w.active[slot] = req

@@ -18,6 +18,7 @@ EXPECTED = {
     "recurrentgemma_2b": (26, 2560, 10, 1, 7680, 256000),
     "phi3_5_moe": (32, 4096, 32, 8, 6400, 32064),
     "chameleon_34b": (48, 8192, 64, 8, 22016, 65536),
+    "lfm2_24b_a2b": (40, 2048, 32, 8, 1536, 65536),
 }
 
 PARAM_RANGES = {
@@ -28,6 +29,7 @@ PARAM_RANGES = {
     "llama4_maverick": (3.8e11, 4.2e11),
     "phi3_5_moe": (4.0e10, 4.4e10),
     "chameleon_34b": (3.2e10, 3.6e10),
+    "lfm2_24b_a2b": (2.3e10, 2.5e10),
 }
 
 

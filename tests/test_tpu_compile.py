@@ -4,10 +4,14 @@ topology: nothing runs and no chip is needed, only the TPU compiler.
 - The engine's jitted prefill and decode steps (``serving.engine.
   build_steps``) at granite_3_8b's published widths, cut to
   ``CHIP_LAYERS`` layers, under the one-chip traffic (``Traffic()``), must
-  compile and leave at least 2 GB of the chip's 16 GiB free.
+  compile and leave at least 2 GB of the chip's 16 GiB free; so must
+  lfm2_24b_a2b's, cut to ``LFM2_LAYERS`` layers with 16 of its 64 experts
+  held, under its benchmark cell's traffic (``LFM2_TRAFFIC``).
 - Each Pallas kernel must be accepted by the TPU compiler at a real width.
 - Both steps must read every f32 projection weight in place through
-  ``wstream_matmul``: no bf16 copy of a weight, no copy of a layer's slice.
+  ``wstream_matmul``: no bf16 copy of a weight, no copy of a layer's slice;
+  lfm2_24b_a2b's steps must hold no large f32 -> bf16 convert either (its
+  experts go through ``moe_gmm``).
 
 The topology is described in a fixture, never at import: only one process
 at a time may load the TPU library.
@@ -26,14 +30,21 @@ from repro.configs import get_config
 from repro.kernels.decode_attention.ops import decode_attention
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.mlstm_chunk.ops import mlstm_chunk
+from repro.kernels.moe_gmm.ops import moe_gmm
 from repro.kernels.rglru_scan.ops import rglru_scan
 from repro.kernels.wstream_matmul.ops import wstream_matmul
-from repro.launch.serve import CHIP_LAYERS, Traffic, cut_depth
+from repro.launch.serve import (CHIP_LAYERS, EXPERT_SHARDS, Traffic,
+                                cut_depth, cut_experts)
 from repro.models import LM
 from repro.serving.engine import build_steps
 
 HBM_BYTES = 16 * 2**30          # one TPU v5e
 HEADROOM_BYTES = 2 * 10**9
+# lfm2_24b_a2b's cut and its benchmark cell's serving: 32 slots live, the
+# KV ring's 32 places full, prompts up to 1536 tokens
+LFM2_LAYERS = 14
+LFM2_TRAFFIC = Traffic(requests=64, prompt_len=1536, decode_slots=32,
+                       max_len=2048)
 
 
 @pytest.fixture(scope="module")
@@ -71,11 +82,13 @@ def _nbytes(tree):
     return sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(tree))
 
 
-def compiled_steps(sharding, n_layers: int, traffic: Traffic):
-    """Compile the engine's steps for granite_3_8b cut to ``n_layers``:
+def compiled_steps(sharding, n_layers: int, traffic: Traffic,
+                   arch: str = "granite_3_8b"):
+    """Compile the engine's steps for ``arch`` cut to ``n_layers`` (and
+    one expert shard, as ``launch/serve.py --layers`` serves it):
     ``(params, cache1, dcache, compiled prefill, compiled decode)``, the
     first three as shapes."""
-    cfg = cut_depth(get_config("granite_3_8b"), n_layers)
+    cfg = cut_experts(cut_depth(get_config(arch), n_layers), EXPERT_SHARDS)
     lm = LM(cfg)
     prefill, decode = build_steps(lm)
     params = _on(sharding, jax.eval_shape(
@@ -96,10 +109,9 @@ def compiled_steps(sharding, n_layers: int, traffic: Traffic):
             decode.lower(params, tok, dcache).compile())
 
 
-def engine_step_bytes(sharding, n_layers: int, traffic: Traffic) -> dict:
-    """Compile the engine's steps for granite_3_8b cut to ``n_layers`` and
-    return the device bytes live at the peak of each phase."""
-    _, cache1, dcache, pre, dec = compiled_steps(sharding, n_layers, traffic)
+def step_bytes(cache1, dcache, pre, dec, traffic: Traffic) -> dict:
+    """The device bytes live at the peak of each phase of compiled
+    steps."""
     pre, dec = pre.memory_analysis(), dec.memory_analysis()
     # prefilled caches wait in the KV ring while every decode slot is busy
     ring = max(traffic.requests - traffic.decode_slots, 0) * _nbytes(cache1)
@@ -113,16 +125,57 @@ def engine_step_bytes(sharding, n_layers: int, traffic: Traffic) -> dict:
 
 
 def test_engine_steps_fit_one_chip(one_chip):
-    peak = engine_step_bytes(one_chip, CHIP_LAYERS, Traffic())
+    _, cache1, dcache, pre, dec = compiled_steps(one_chip, CHIP_LAYERS,
+                                                 Traffic())
+    peak = step_bytes(cache1, dcache, pre, dec, Traffic())
     for phase, nbytes in peak.items():
         assert nbytes <= HBM_BYTES - HEADROOM_BYTES, (phase, nbytes)
+
+
+# ``%name = bf16[2048,1536]{...} convert(`` anywhere in the compiled text
+_CONVERT = re.compile(r"= bf16\[([\d,]*)\]\S* convert\(")
+
+
+def _weight_shapes(params) -> set:
+    """The shapes of every weight of a million elements or more: a whole
+    stack, a layer of it, an expert of it, either orientation."""
+    out = set()
+    for leaf in jax.tree.leaves(params):
+        if leaf.size < 2**20:
+            continue
+        for shape in (leaf.shape, leaf.shape[1:], leaf.shape[2:]):
+            if len(shape) >= 2:
+                out |= {shape, (*shape[:-2], shape[-1], shape[-2])}
+    return out
+
+
+def test_lfm2_steps_fit_one_chip_without_weight_converts(one_chip):
+    """lfm2_24b_a2b at its cut: both steps fit one chip with 2 GB free, and
+    neither rounds a weight (a million elements or more: a projection, an
+    expert stack, the tied embedding) from f32 to bf16 in HBM."""
+    params, cache1, dcache, pre, dec = compiled_steps(
+        one_chip, LFM2_LAYERS, LFM2_TRAFFIC, arch="lfm2_24b_a2b")
+    peak = step_bytes(cache1, dcache, pre, dec, LFM2_TRAFFIC)
+    for phase, nbytes in peak.items():
+        assert nbytes <= HBM_BYTES - HEADROOM_BYTES, (phase, nbytes)
+    weights = _weight_shapes(params)
+    for step in (pre, dec):
+        text = step.as_text()
+        assert "moe_gmm" in text and "wstream_matmul" in text
+        for dims in _CONVERT.findall(text):
+            shape = tuple(int(d) for d in dims.split(",") if d)
+            while shape[:1] == (1,):
+                shape = shape[1:]
+            assert shape not in weights, shape
 
 
 # Real widths: decode attention at granite_3_8b (K=8, hd=128, S=2048),
 # flash attention at granite_3_8b (32/8 heads x 128), the RG-LRU scan at
 # recurrentgemma_2b (W=2560), the mLSTM chunk at xlstm (hd 512, S=2048),
 # the weight-streaming matmul at granite_3_8b's MLP (decode rows) and
-# unembedding (prefill rows, vocabulary 49155).
+# unembedding (prefill rows, vocabulary 49155), the grouped expert FFN at
+# lfm2_24b_a2b's 16 held experts (decode: 32 slots x top-4 rows; prefill:
+# 1536 tokens x top-4).
 KERNEL_CASES = {
     "decode_attention": (decode_attention, [
         ((8, 32, 128), jnp.float32), ((8, 2048, 8, 128), jnp.float32),
@@ -137,6 +190,14 @@ KERNEL_CASES = {
         ((8, 4096), jnp.float32), ((4096, 12800), jnp.float32)]),
     "wstream_matmul_ragged": (wstream_matmul, [
         ((768, 4096), jnp.float32), ((4096, 49155), jnp.float32)]),
+    "moe_gmm": (moe_gmm, [
+        ((128, 2048), jnp.float32), ((16, 2048, 1536), jnp.float32),
+        ((16, 2048, 1536), jnp.float32), ((16, 1536, 2048), jnp.float32),
+        ((17,), jnp.int32)]),
+    "moe_gmm_prefill": (moe_gmm, [
+        ((6144, 2048), jnp.float32), ((16, 2048, 1536), jnp.float32),
+        ((16, 2048, 1536), jnp.float32), ((16, 1536, 2048), jnp.float32),
+        ((17,), jnp.int32)]),
     "mlstm_chunk": (mlstm_chunk, [
         ((8, 2048, 512), jnp.float32), ((8, 2048, 512), jnp.float32),
         ((8, 2048, 512), jnp.float32), ((8, 2048), jnp.float32),

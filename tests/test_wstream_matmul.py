@@ -87,6 +87,7 @@ def test_layer_params_views_only_dense_projections():
     assert p["attn"]["wq"].shape == (8, 16)
     assert p["attn"]["bq"].shape == (16,)
     assert p["ln1"]["w"].shape == (8,)
-    # expert stacks and routers are not dense projections: sliced
+    # an expert stack is viewed like a projection, a router is sliced
+    assert isinstance(p["ffn"]["wi"], L.LayerWeight)
     assert p["ffn"]["wi"].shape == (2, 8, 16)
     assert p["ffn"]["router"].shape == (8, 2)
