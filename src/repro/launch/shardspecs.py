@@ -135,18 +135,18 @@ def cache_specs(cfg: ModelConfig, abstract_cache, batch_axes: Tuple[str, ...],
     decode optimization); None = unsharded."""
     b = batch_axes if batch_axes else None
 
-    def kv5(_):   # (G/L, B, S, K, H)
-        return P(None, b, kv_seq_axis, None, None)
+    def kv(a):   # (G/L, B, S, K, H) or (G/L, B, S, K * H)
+        return P(None, b, kv_seq_axis, *(None,) * (a.ndim - 3))
 
     fam = cfg.family
     if fam in ("dense", "moe", "vlm"):
         return {
-            "slots": tuple({"k": kv5(None), "v": kv5(None)}
-                           for _ in abstract_cache["slots"]),
+            "slots": tuple({"k": kv(s["k"]), "v": kv(s["v"])}
+                           for s in abstract_cache["slots"]),
             "pos": P(),
         }
     if fam == "audio":
-        return {"k": kv5(None), "v": kv5(None),
+        return {"k": kv(abstract_cache["k"]), "v": kv(abstract_cache["v"]),
                 "ck": P(None, b, None, None, None),
                 "cv": P(None, b, None, None, None), "pos": P()}
     if fam == "ssm":
@@ -160,9 +160,9 @@ def cache_specs(cfg: ModelConfig, abstract_cache, batch_axes: Tuple[str, ...],
     if fam == "hybrid":
         def slot_spec(slot, stacked: bool):
             n = 1 if stacked else 0
-            if isinstance(slot, dict):      # attention: k/v (G?,B,S,K,H)
-                return {"k": P(*(None,) * n, b, None, None, None),
-                        "v": P(*(None,) * n, b, None, None, None)}
+            if isinstance(slot, dict):      # attention: k/v (G?,B,S,K[,H])
+                return {name: P(*(None,) * n, b, *(None,) * (a.ndim - n - 1))
+                        for name, a in slot.items()}
             # rec: (conv (G?,B,cw-1,W), h (G?,B,W))
             return (P(*(None,) * n, b, None, "model"),
                     P(*(None,) * n, b, "model"))

@@ -239,36 +239,85 @@ def gqa_attention(q, k, v, *, causal: bool, window: Optional[int] = None,
     return out.reshape(B, Sq, Hq, hd).astype(q.dtype)
 
 
+def kv_cache_shape(batch: int, seq: int, n_kv_heads: int, head_dim: int):
+    """The shape of one attention layer's K or V cache, chosen by
+    ``head_dim``: ``(B, S, K, hd)`` where ``hd`` is a multiple of 128, else
+    ``(B, S, K * hd)``, so that the minor dimension still fills the TPU's
+    128 lanes. A ``(..., K, hd)`` cache with a narrow ``hd`` is stored with
+    the sequence minor instead, and the layer step copies it into the
+    attention's layout and back. The attention functions read which layout
+    a cache has from its rank."""
+    if head_dim % 128 == 0:
+        return (batch, seq, n_kv_heads, head_dim)
+    return (batch, seq, n_kv_heads * head_dim)
+
+
+def _valid_slots(Sc, pos, window):
+    """(B|1, Sc): which cache slots hold a position at or before ``pos``."""
+    slots = jnp.arange(Sc)
+    posv = jnp.asarray(pos)
+    posb = posv if posv.ndim else posv[None]           # (B,) or (1,)
+    if window is None:
+        return slots[None, :] <= posb[:, None]
+    kpos = posb[:, None] - jnp.mod(posb[:, None] - slots[None, :], Sc)
+    return kpos >= 0
+
+
+def _softmax_last(scores):
+    # numerically-stable softmax; reduction over a (possibly sharded) Sc dim
+    m = jnp.max(scores, axis=-1, keepdims=True)
+    e = jnp.exp(scores - m)
+    s = jnp.sum(e, axis=-1, keepdims=True)
+    return e / s
+
+
 def decode_attention(q, k_cache, v_cache, pos, *, window: Optional[int] = None):
     """Single-token GQA attention over a KV cache.
 
-    q: (B, 1, Hq, hd); caches: (B, Sc, K, hd) where Sc = max_len (no window)
-    or Sc = window (rotating cache). ``pos`` is the current absolute position:
-    a scalar, or a (B,) vector for continuous batching (per-slot positions).
-    Keys in a rotating cache at slot j hold absolute position
+    q: (B, 1, Hq, hd); caches in either layout of ``kv_cache_shape``:
+    (B, Sc, K, hd), or (B, Sc, K * hd), which is read without splitting
+    its minor dimension. Sc = max_len (no window) or Sc = window (rotating
+    cache). ``pos`` is the current absolute position: a scalar, or a (B,)
+    vector for continuous batching (per-slot positions). Keys in a
+    rotating cache at slot j hold absolute position
     pos - ((pos - j) mod W); empty slots map to negative positions -> masked.
     """
+    if k_cache.ndim == 3:
+        return _decode_attention_flat(q, k_cache, v_cache, pos, window)
     B, _, Hq, hd = q.shape
     Sc, K = k_cache.shape[1], k_cache.shape[2]
     G = Hq // K
     qf = q.reshape(B, K, G, hd).astype(jnp.float32)
     scores = jnp.einsum("bkgh,btkh->bkgt", qf, k_cache.astype(jnp.float32))
     scores = scores / math.sqrt(hd)
-    slots = jnp.arange(Sc)
-    posv = jnp.asarray(pos)
-    posb = posv if posv.ndim else posv[None]           # (B,) or (1,)
-    if window is None:
-        valid = slots[None, :] <= posb[:, None]        # (B|1, Sc)
-    else:
-        kpos = posb[:, None] - jnp.mod(posb[:, None] - slots[None, :], Sc)
-        valid = kpos >= 0
+    valid = _valid_slots(Sc, pos, window)
     scores = jnp.where(valid[:, None, None, :], scores, -1e30)
-    # numerically-stable softmax; reduction over a (possibly sharded) Sc dim
-    m = jnp.max(scores, axis=-1, keepdims=True)
-    e = jnp.exp(scores - m)
-    s = jnp.sum(e, axis=-1, keepdims=True)
-    probs = e / s
+    probs = _softmax_last(scores)
     out = jnp.einsum("bkgt,btkh->bkgh", probs, v_cache.astype(jnp.float32))
+    return out.reshape(B, 1, Hq, hd).astype(q.dtype)
+
+
+def _decode_attention_flat(q, k_cache, v_cache, pos, window):
+    """``decode_attention`` over (B, Sc, K * hd) caches. The query becomes
+    block-diagonal, (B, Hq, K * hd) with head (k, g) zero outside kv head
+    k's ``hd`` columns, so one contraction over ``K * hd`` gives every
+    head's scores; ``probs @ V`` gives (B, Hq, K * hd), of which head (k,
+    g) keeps block k. The zeros are exact: each score and output sums the
+    same products as the split layout's."""
+    B, _, Hq, hd = q.shape
+    Sc = k_cache.shape[1]
+    K = k_cache.shape[2] // hd
+    G = Hq // K
+    own = jnp.eye(K, dtype=bool)[None, :, None, :, None]   # (1,K,1,K,1)
+    qf = q.reshape(B, K, G, 1, hd).astype(jnp.float32)
+    qbd = jnp.where(own, qf, 0.0).reshape(B, Hq, K * hd)
+    scores = jnp.einsum("bqc,btc->bqt", qbd, k_cache.astype(jnp.float32))
+    scores = scores / math.sqrt(hd)
+    valid = _valid_slots(Sc, pos, window)
+    scores = jnp.where(valid[:, None, :], scores, -1e30)
+    probs = _softmax_last(scores)
+    out = jnp.einsum("bqt,btc->bqc", probs, v_cache.astype(jnp.float32))
+    out = jnp.where(own, out.reshape(B, K, G, K, hd), 0.0).sum(axis=3)
     return out.reshape(B, 1, Hq, hd).astype(q.dtype)
 
 
@@ -280,9 +329,16 @@ def dist_decode_attention(q, k_cache, v_cache, k_new, v_new, pos):
     not O(KV). The cache write lands only on the owning shard.
 
     q, k_new, v_new: (B, 1, Hq|K, hd) replicated over the seq axis;
-    caches: (B, Sc, K, hd) sharded on dim 1. pos: scalar.
-    Returns (out (B,1,Hq,hd), k_cache, v_cache).
+    caches: (B, Sc, K, hd) or (B, Sc, K * hd), sharded on dim 1. pos:
+    scalar. Returns (out (B,1,Hq,hd), k_cache, v_cache), the caches in the
+    layout they came in.
     """
+    if k_cache.ndim == 3:
+        split = k_new.shape[:1] + k_cache.shape[1:2] + k_new.shape[2:]
+        out, kc, vc = dist_decode_attention(
+            q, k_cache.reshape(split), v_cache.reshape(split), k_new, v_new,
+            pos)
+        return out, kc.reshape(k_cache.shape), vc.reshape(v_cache.shape)
     mesh = SH.mesh()
     seq_ax = SH.rule("kv_seq")
     batch_ax = SH.rule("kv_batch")
@@ -327,9 +383,20 @@ def dist_decode_attention(q, k_cache, v_cache, k_new, v_new, pos):
     return out, kc, vc
 
 
+def _as_cache_rows(cache, kv):
+    """``kv`` (B, S, K, hd) in ``cache``'s layout: (B, S, K * hd) for a
+    rank-3 cache, else unchanged."""
+    if cache.ndim == 3:
+        return kv.reshape(*kv.shape[:2], cache.shape[2])
+    return kv
+
+
 def cache_update_decode(cache, new, pos, *, window: Optional[int] = None):
     """Write one token's k or v (B, 1, K, hd) into the cache at ``pos``
-    (scalar, or (B,) per-slot positions for continuous batching)."""
+    (scalar, or (B,) per-slot positions for continuous batching). A
+    (B, Sc, K * hd) cache (``kv_cache_shape``) takes one ``K * hd`` row
+    per slot, a (B, Sc, K, hd) cache the token as it is."""
+    new = _as_cache_rows(cache, new)
     posv = jnp.asarray(pos)
     slot = posv if window is None else jnp.mod(posv, cache.shape[1])
     if posv.ndim == 0:
@@ -340,7 +407,9 @@ def cache_update_decode(cache, new, pos, *, window: Optional[int] = None):
 
 
 def cache_fill_prefill(cache, k, *, window: Optional[int] = None):
-    """Write a full prompt's keys/values (B, S, K, hd) into a fresh cache."""
+    """Write a full prompt's keys/values (B, S, K, hd) into a fresh cache
+    of either layout of ``kv_cache_shape``."""
+    k = _as_cache_rows(cache, k)
     S, Sc = k.shape[1], cache.shape[1]
     if window is None or S <= Sc:
         if S > Sc:
@@ -441,8 +510,9 @@ def attn_decode(p, x, cfg, k_cache, v_cache, pos, *, window=None):
         return out, k_cache, v_cache
     k_cache = cache_update_decode(k_cache, k, pos, window=window)
     v_cache = cache_update_decode(v_cache, v, pos, window=window)
-    k_cache = constrain(k_cache, "kv_batch", "kv_seq", None, None)
-    v_cache = constrain(v_cache, "kv_batch", "kv_seq", None, None)
+    rest = (None,) * (k_cache.ndim - 2)
+    k_cache = constrain(k_cache, "kv_batch", "kv_seq", *rest)
+    v_cache = constrain(v_cache, "kv_batch", "kv_seq", *rest)
     out = decode_attention(q, k_cache, v_cache, pos, window=window)
     out = proj(out.reshape(x.shape[0], 1, cfg.q_dim), p["wo"])
     return out, k_cache, v_cache

@@ -178,7 +178,8 @@ def _slot_cache(cfg, kind, batch, max_len, dtype, window):
         return _rec_state(cfg, batch, dtype)
     Sc = min(max_len, window or cfg.window or max_len)
     def z():
-        return jnp.zeros((batch, Sc, cfg.n_kv_heads, cfg.head_dim), dtype)
+        return jnp.zeros(L.kv_cache_shape(batch, Sc, cfg.n_kv_heads,
+                                          cfg.head_dim), dtype)
     return {"k": z(), "v": z()}
 
 

@@ -85,13 +85,18 @@ def init_params(cfg, key, dtype=jnp.bfloat16):
 
 def init_cache(cfg, batch: int, max_len: int, dtype=jnp.bfloat16,
                window: Optional[int] = None):
+    """Per stack, per slot of its period, a leaf stacked over the stack's
+    ``G`` groups: an attention slot's ``k`` and ``v`` are ``(G, B, Sc, K,
+    hd)`` where ``head_dim`` is a multiple of 128, else ``(G, B, Sc, K *
+    hd)`` (``layers.kv_cache_shape``); a convolution slot's ``conv`` is
+    ``(G, B, W - 1, D)``. ``Sc`` is ``max_len``, or the window if shorter."""
     Sc = min(max_len, window) if window else max_len
 
     def entry(mixer, G):
         if mixer == "conv":
             return {"conv": jnp.zeros((G, batch, cfg.conv_width - 1,
                                        cfg.d_model), dtype)}
-        kv = (G, batch, Sc, cfg.n_kv_heads, cfg.head_dim)
+        kv = (G, *L.kv_cache_shape(batch, Sc, cfg.n_kv_heads, cfg.head_dim))
         return {"k": jnp.zeros(kv, dtype), "v": jnp.zeros(kv, dtype)}
 
     cache = {name: tuple(entry(mixer, G) for mixer, _ in kinds)

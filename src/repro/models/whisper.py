@@ -62,8 +62,9 @@ def init_cache(cfg, batch: int, max_len: int, dtype=jnp.bfloat16,
     Sc = min(max_len, window) if window else max_len
     def kv(s):
         return jnp.zeros((Ld, batch, s, cfg.n_kv_heads, cfg.head_dim), dtype)
+    self_kv = (Ld, *L.kv_cache_shape(batch, Sc, cfg.n_kv_heads, cfg.head_dim))
     return {
-        "k": kv(Sc), "v": kv(Sc),
+        "k": jnp.zeros(self_kv, dtype), "v": jnp.zeros(self_kv, dtype),
         "ck": kv(cfg.encoder_seq), "cv": kv(cfg.encoder_seq),
         "pos": jnp.zeros((), jnp.int32),
     }

@@ -46,6 +46,21 @@ err = float(jnp.max(jnp.abs(out[:, 0] - ref)))
 assert err < 2e-5, err
 np.testing.assert_allclose(np.asarray(kc2), np.asarray(kc_ref), atol=1e-6)
 np.testing.assert_allclose(np.asarray(vc2), np.asarray(vc_ref), atol=1e-6)
+
+# the lane-dense (B, S, K * hd) cache: the same result, the caches handed
+# back in that layout
+fs = NamedSharding(mesh, P("data", "model", None))
+with mesh:
+    out_f, kc3, vc3 = jax.jit(run, in_shardings=(
+        NamedSharding(mesh, P("data",)), fs, fs,
+        NamedSharding(mesh, P("data",)), NamedSharding(mesh, P("data",))
+    ))(q, kc.reshape(B, S, K * hd), vc.reshape(B, S, K * hd), kn, vn)
+assert kc3.shape == vc3.shape == (B, S, K * hd)
+np.testing.assert_allclose(np.asarray(out_f), np.asarray(out), atol=1e-6)
+np.testing.assert_allclose(np.asarray(kc3).reshape(kc_ref.shape),
+                           np.asarray(kc_ref), atol=1e-6)
+np.testing.assert_allclose(np.asarray(vc3).reshape(vc_ref.shape),
+                           np.asarray(vc_ref), atol=1e-6)
 print("DIST_ATTENTION_OK", err)
 """
 

@@ -1,5 +1,7 @@
 """Prefill+decode must reproduce teacher-forced logits for every family,
 including sliding-window attention, dropless-MoE, recurrent state handoff."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -21,10 +23,22 @@ CASES = [
 ]
 
 
-@pytest.mark.parametrize("arch,window,moe", CASES)
-def test_prefill_decode_matches_teacher_forcing(arch, window, moe):
-    cfg = get_config(arch).reduced()
-    assert bool(cfg.n_experts) == moe      # every MoE layer is dropless
+# The reduced configs' head_dim is 64, so their KV caches take the
+# lane-dense (B, S, K * hd) layout; at 128 they keep (B, S, K, hd).
+SPLIT_KV_CASES = [
+    ("granite_3_8b", None),
+    ("granite_3_8b", 8),
+    ("recurrentgemma_2b", None),
+    ("whisper_large_v3", None),
+    ("lfm2_24b_a2b", None),
+]
+
+
+def _at_head_dim_128(cfg):
+    return dataclasses.replace(cfg, head_dim=128)
+
+
+def _check_teacher_forcing(cfg, window):
     lm = LM(cfg)
     key = jax.random.key(1)
     params = lm.init(key)
@@ -40,12 +54,23 @@ def test_prefill_decode_matches_teacher_forcing(arch, window, moe):
         lg, cache = lm.decode_step(params, batch["tokens"][:, t], cache,
                                    window=window)
         errs.append(float(jnp.max(jnp.abs(lg - full[:, t]))))
-    assert max(errs) < 2e-3, (arch, errs)
+    assert max(errs) < 2e-3, (cfg.name, errs)
 
 
-def test_vector_pos_decode_matches_scalar():
-    """Per-slot positions (continuous batching) == scalar-pos decode."""
-    cfg = get_config("qwen1_5_4b").reduced()
+@pytest.mark.parametrize("arch,window,moe", CASES)
+def test_prefill_decode_matches_teacher_forcing(arch, window, moe):
+    cfg = get_config(arch).reduced()
+    assert bool(cfg.n_experts) == moe      # every MoE layer is dropless
+    _check_teacher_forcing(cfg, window)
+
+
+@pytest.mark.parametrize("arch,window", SPLIT_KV_CASES)
+def test_prefill_decode_matches_teacher_forcing_split_kv(arch, window):
+    _check_teacher_forcing(_at_head_dim_128(get_config(arch).reduced()),
+                           window)
+
+
+def _check_vector_pos(cfg):
     lm = LM(cfg)
     key = jax.random.key(3)
     params = lm.init(key)
@@ -59,3 +84,13 @@ def test_vector_pos_decode_matches_scalar():
     cache_v["pos"] = jnp.full((B,), P, jnp.int32)
     lg2, _ = lm.decode_step(params, tok, cache_v)
     assert float(jnp.max(jnp.abs(lg1 - lg2))) < 1e-5
+
+
+def test_vector_pos_decode_matches_scalar():
+    """Per-slot positions (continuous batching) == scalar-pos decode."""
+    _check_vector_pos(get_config("qwen1_5_4b").reduced())
+
+
+def test_vector_pos_decode_matches_scalar_split_kv():
+    """The same at head_dim 128, where the cache keeps (B, S, K, hd)."""
+    _check_vector_pos(_at_head_dim_128(get_config("qwen1_5_4b").reduced()))

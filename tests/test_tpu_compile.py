@@ -12,12 +12,15 @@ topology: nothing runs and no chip is needed, only the TPU compiler.
   ``wstream_matmul``: no bf16 copy of a weight, no copy of a layer's slice;
   lfm2_24b_a2b's steps must hold no large f32 -> bf16 convert either (its
   experts go through ``moe_gmm``).
+- Neither model's steps may copy or transpose an attention layer's K or V
+  cache: the cache is stored in the layout the attention reads.
 
 The topology is described in a fixture, never at import: only one process
 at a time may load the TPU library.
 """
 import collections
 import functools
+import math
 import os
 import re
 
@@ -256,3 +259,27 @@ def test_steps_stream_weights_in_place(one_chip):
             for m in _KERNEL_W.findall(text))
         unembed = read.pop((1, K, N), 0) + read.pop((1, N, K), 0)
         assert unembed == 1 and read == stacks, (read, stacks)
+
+
+_MOVE = re.compile(r"%\S+ = f32\[([\d,]*)\]\S* (?:copy|transpose)\(")
+
+
+@pytest.mark.parametrize("arch", ["granite_3_8b", "lfm2_24b_a2b"])
+def test_steps_leave_kv_cache_in_place(one_chip, arch):
+    """No f32 array with as many elements as one attention layer's K or V
+    cache comes from a ``copy`` or a ``transpose`` in ``jit_prefill`` or
+    ``jit_decode``: at head_dim 64 (lfm2_24b_a2b) as at 128 (granite), the
+    cache is stored in the layout that the attention and the cache update
+    read and write, so no layer step moves it out and back."""
+    n_layers, traffic = ((LFM2_LAYERS, LFM2_TRAFFIC) if arch == "lfm2_24b_a2b"
+                         else (CHIP_LAYERS, Traffic()))
+    _, cache1, dcache, pre, dec = compiled_steps(one_chip, n_layers, traffic,
+                                                 arch=arch)
+    for cache, step in ((cache1, pre), (dcache, dec)):
+        kv = {leaf.size // leaf.shape[0]
+              for slot in cache["slots"] for name, leaf in slot.items()
+              if name in ("k", "v")}
+        assert kv
+        moved = [dims for dims in _MOVE.findall(step.as_text())
+                 if math.prod(int(d) for d in dims.split(",") if d) in kv]
+        assert not moved, moved
