@@ -1,5 +1,5 @@
-"""Benchmark driver: one module per paper figure/table + roofline + kernels
-+ the simulator-throughput benchmark (``simperf``).
+"""Benchmark driver: one module per paper figure/table + kernels + the
+simulator-throughput benchmark (``simperf``).
 
 Usage:
     PYTHONPATH=src python -m benchmarks.run [--fast] [--fleet] [--only fig5,...]
@@ -25,7 +25,6 @@ Targets (the README's figure-reproduction table is generated from these):
     fig14control  control-plane chaos: fail-safe vs oracle vs naive control
     fig15multitenant multi-tenant day: SLO classes + preemption + locality
     simperf       simulator event-throughput benchmark (perf gate)
-    roofline      per-(arch x shape) roofline table from dry-run artifacts
     kernels       interpret-mode Pallas kernel microbenchmarks vs jnp oracles
     beyond        beyond-paper ablation studies
 """
@@ -38,8 +37,8 @@ import traceback
 
 SUITES = ("fig4", "fig5", "fig6", "fig7", "fig8", "fig9cluster",
           "fig10hetero", "fig11fleet", "fig12autoscale", "fig13chaos",
-          "fig14control", "fig15multitenant", "simperf", "roofline",
-          "kernels", "beyond")
+          "fig14control", "fig15multitenant", "simperf", "kernels",
+          "beyond")
 
 # one-liners for --list / unknown-target help, same order as SUITES
 DESCRIPTIONS = {
@@ -56,7 +55,6 @@ DESCRIPTIONS = {
     "fig14control": "control-plane chaos: fail-safe vs oracle vs naive control",
     "fig15multitenant": "multi-tenant day: SLO classes + preemption + locality",
     "simperf": "simulator event-throughput benchmark (perf gate)",
-    "roofline": "per-(arch x shape) roofline table from dry-run artifacts",
     "kernels": "interpret-mode Pallas kernel microbenchmarks vs jnp oracles",
     "beyond": "beyond-paper ablation studies",
 }
@@ -101,7 +99,7 @@ def main() -> None:
                             fig10_hetero_dyngpu, fig11_elastic_fleet,
                             fig12_autoscale_tariff, fig13_chaos,
                             fig14_control_chaos, fig15_multitenant,
-                            kernels_bench, roofline, sim_throughput)
+                            kernels_bench, sim_throughput)
     mods = {
         "fig4": fig4_power_curves, "fig5": fig5_static_slo,
         "fig6": fig6_queueing, "fig7": fig7_slo_scaling,
@@ -112,7 +110,7 @@ def main() -> None:
         "fig14control": fig14_control_chaos,
         "fig15multitenant": fig15_multitenant,
         "simperf": sim_throughput,
-        "roofline": roofline, "kernels": kernels_bench,
+        "kernels": kernels_bench,
         "beyond": beyond_ablations,
     }
     results = []

@@ -1,25 +1,21 @@
-"""Quickstart: build a small model from a config, train it a few steps on
-synthetic data, then generate greedily with the prefill/decode API.
+"""Quickstart: build a small model from a config with random weights, then
+generate greedily with the prefill/decode API the serving engine uses.
 
 Run:  PYTHONPATH=src python examples/quickstart.py
 """
+import jax
 import jax.numpy as jnp
 
 from repro.configs import get_config
 from repro.models import LM
-from repro.training.train_loop import train
 
 
 def main():
     cfg = get_config("qwen1.5-4b").reduced()     # 2-layer smoke variant
     print(f"model: {cfg.name} ({cfg.param_count()/1e6:.1f}M params)")
 
-    params, hist = train(cfg, steps=30, batch_size=4, seq_len=64,
-                         log_every=10, remat=False)
-    print(f"loss: {hist[0]:.3f} -> {hist[-1]:.3f}")
-
-    # greedy generation through the serving API
     lm = LM(cfg)
+    params = lm.init(jax.random.key(0), jnp.float32)
     prompt = jnp.arange(12)[None, :] % cfg.vocab_size
     cache = lm.init_cache(1, 48, dtype=jnp.float32)
     logits, cache = lm.prefill(params, {"tokens": prompt}, cache)

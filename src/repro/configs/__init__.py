@@ -1,9 +1,7 @@
 from repro.configs.base import (
-    ARCH_ALIASES, ARCH_IDS, INPUT_SHAPES, InputShape, ModelConfig,
-    all_configs, get_config,
+    ARCH_ALIASES, ARCH_IDS, ModelConfig, all_configs, get_config,
 )
 
 __all__ = [
-    "ARCH_ALIASES", "ARCH_IDS", "INPUT_SHAPES", "InputShape",
-    "ModelConfig", "all_configs", "get_config",
+    "ARCH_ALIASES", "ARCH_IDS", "ModelConfig", "all_configs", "get_config",
 ]

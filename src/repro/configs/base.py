@@ -1,4 +1,4 @@
-"""Config system: model architecture configs + canonical input shapes.
+"""Config system: model architecture configs.
 
 Every assigned architecture gets one module in ``repro/configs`` exporting
 ``CONFIG`` (the exact published shape, cited) and relying on
@@ -211,24 +211,6 @@ class ModelConfig:
             changes["head_dim"] = 128
         return dataclasses.replace(self, **changes)
 
-    def with_window(self, window: int) -> "ModelConfig":
-        return dataclasses.replace(self, window=window)
-
-
-@dataclass(frozen=True)
-class InputShape:
-    name: str
-    seq_len: int
-    global_batch: int
-    kind: str                       # "train" | "prefill" | "decode"
-
-
-INPUT_SHAPES = {
-    "train_4k": InputShape("train_4k", 4_096, 256, "train"),
-    "prefill_32k": InputShape("prefill_32k", 32_768, 32, "prefill"),
-    "decode_32k": InputShape("decode_32k", 32_768, 128, "decode"),
-    "long_500k": InputShape("long_500k", 524_288, 1, "decode"),
-}
 
 ARCH_IDS = (
     "qwen1_5_4b",

@@ -1,5 +1,5 @@
 """Jitted public wrapper for flash attention with GQA support and a pure-jnp
-fallback (used on CPU / in dry-runs; the Pallas path targets TPU)."""
+fallback (used on CPU; the Pallas path targets TPU)."""
 from __future__ import annotations
 
 import functools

@@ -1,6 +1,5 @@
 """Jitted wrapper for the grouped expert FFN: one layer of expert stacks or
-a single layer's experts, rows padded to whole tiles, and a backward pass
-through the plain grouped matmuls."""
+a single layer's experts, rows padded to whole tiles."""
 from __future__ import annotations
 
 import functools
@@ -26,36 +25,6 @@ def plain_ffn(x, wg, wi, wo, offsets, layer):
     return mm(jax.nn.silu(mm(x, wg)) * mm(x, wi), wo)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
-def _ffn(x, wg, wi, wo, offsets, layer, interpret):
-    M = x.shape[0]
-    tm = M if M <= TM else TM
-    pad = -M % tm
-    xp = jnp.pad(x, ((0, pad), (0, 0))) if pad else x
-    F = wg.shape[-1]
-    tf = TF if F % TF == 0 else F
-    y = moe_gmm_pallas(xp, wg, wi, wo, offsets, layer, tm=tm, tf=tf,
-                       interpret=interpret)
-    return y[:M] if pad else y
-
-
-def _ffn_fwd(x, wg, wi, wo, offsets, layer, interpret):
-    return (_ffn(x, wg, wi, wo, offsets, layer, interpret),
-            (x, wg, wi, wo, offsets, layer))
-
-
-def _ffn_bwd(interpret, res, g):
-    x, wg, wi, wo, offsets, layer = res
-    _, vjp = jax.vjp(
-        lambda *a: plain_ffn(*a, offsets, layer).astype(jnp.float32),
-        x, wg, wi, wo)
-    dx, dwg, dwi, dwo = vjp(g)
-    return dx.astype(x.dtype), dwg, dwi, dwo, None, None
-
-
-_ffn.defvjp(_ffn_fwd, _ffn_bwd)
-
-
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def moe_gmm(x, wg, wi, wo, offsets, layer=0, *, interpret: bool):
     """x: (M, K), rows sorted by expert; wg, wi: (E, K, F) float32 and wo:
@@ -66,5 +35,13 @@ def moe_gmm(x, wg, wi, wo, offsets, layer=0, *, interpret: bool):
     accumulation; rows past ``offsets[E]`` are undefined."""
     if wg.ndim == 3:
         wg, wi, wo = wg[None], wi[None], wo[None]
-    return _ffn(x, wg, wi, wo, offsets.astype(jnp.int32),
-                jnp.asarray(layer, jnp.int32), interpret)
+    offsets, layer = offsets.astype(jnp.int32), jnp.asarray(layer, jnp.int32)
+    M = x.shape[0]
+    tm = M if M <= TM else TM
+    pad = -M % tm
+    xp = jnp.pad(x, ((0, pad), (0, 0))) if pad else x
+    F = wg.shape[-1]
+    tf = TF if F % TF == 0 else F
+    y = moe_gmm_pallas(xp, wg, wi, wo, offsets, layer, tm=tm, tf=tf,
+                       interpret=interpret)
+    return y[:M] if pad else y

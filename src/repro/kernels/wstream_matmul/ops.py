@@ -1,6 +1,6 @@
 """Jitted wrapper for the weight-streaming matmul: any leading dims on
-``x``, a 2-D weight or one layer of a stack, either the layout the TPU
-stores the weight in or a weight given as (N, K), and a backward pass."""
+``x``, a 2-D weight or one layer of a stack, and either the layout the
+TPU stores the weight in or a weight given as (N, K)."""
 from __future__ import annotations
 
 import functools
@@ -19,35 +19,6 @@ def _stored_transposed(K: int, N: int) -> bool:
     return N % LANE != 0 and K % LANE == 0
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _stream(x, w, layer, interpret, transposed):
-    if transposed:
-        return wstream_matmul_pallas(x, w, layer, transposed=True,
-                                     interpret=interpret)
-    K, N = w.shape[1:]
-    if _stored_transposed(K, N):
-        return wstream_matmul_pallas(x, jnp.swapaxes(w, 1, 2), layer,
-                                     transposed=True, interpret=interpret)
-    return wstream_matmul_pallas(x, w, layer, interpret=interpret)
-
-
-def _stream_fwd(x, w, layer, interpret, transposed):
-    return _stream(x, w, layer, interpret, transposed), (x, w, layer)
-
-
-def _stream_bwd(interpret, transposed, res, g):
-    x, w, layer = res
-    wl = jax.lax.dynamic_index_in_dim(w, layer, keepdims=False)
-    wl = wl.T if transposed else wl
-    dx = (g @ wl.T).astype(x.dtype)
-    dwl = x.T.astype(w.dtype) @ g
-    dw = jnp.zeros_like(w).at[layer].set(dwl.T if transposed else dwl)
-    return dx, dw, None
-
-
-_stream.defvjp(_stream_fwd, _stream_bwd)
-
-
 @functools.partial(jax.jit, static_argnames=("interpret", "transposed"))
 def wstream_matmul(x, w, layer=0, *, interpret: bool,
                    transposed: bool = False):
@@ -56,6 +27,9 @@ def wstream_matmul(x, w, layer=0, *, interpret: bool,
     the product is x @ w.T. Returns (..., N) float32: the product with both
     operands rounded to bfloat16 and f32 accumulation."""
     w = w if w.ndim == 3 else w[None]
-    y = _stream(x.reshape(-1, x.shape[-1]), w, jnp.asarray(layer, jnp.int32),
-                interpret, transposed)
+    xf, layer = x.reshape(-1, x.shape[-1]), jnp.asarray(layer, jnp.int32)
+    if not transposed and _stored_transposed(*w.shape[1:]):
+        w, transposed = jnp.swapaxes(w, 1, 2), True
+    y = wstream_matmul_pallas(xf, w, layer, transposed=transposed,
+                              interpret=interpret)
     return y.reshape(*x.shape[:-1], y.shape[-1])

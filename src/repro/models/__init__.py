@@ -1,3 +1,3 @@
-from repro.models.api import LM, make_batch_specs, make_demo_batch
+from repro.models.api import LM, make_demo_batch
 
-__all__ = ["LM", "make_batch_specs", "make_demo_batch"]
+__all__ = ["LM", "make_demo_batch"]

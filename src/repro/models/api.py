@@ -1,17 +1,16 @@
-"""Unified model API: one entry point per family, shared loss/sampling.
+"""Unified model API: one entry point per family.
 
 Usage:
     lm = LM(cfg)
     params = lm.init(key, dtype)
-    logits, aux = lm.forward_train(params, batch)
-    loss = lm.loss(params, batch)
+    logits, aux = lm.forward_train(params, batch)   # teacher forcing
     cache = lm.init_cache(batch_size, max_len)
     logits, cache = lm.prefill(params, batch, cache)
     logits, cache = lm.decode_step(params, token, cache)
+
+A sliding window, where a config has one, is ``cfg.window``.
 """
 from __future__ import annotations
-
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -28,8 +27,6 @@ _FAMILY_MODULES = {
     "hybrid": rglru,
 }
 
-MOE_AUX_WEIGHT = 0.01
-
 
 class LM:
     def __init__(self, cfg: ModelConfig):
@@ -40,55 +37,18 @@ class LM:
     def init(self, key, dtype=jnp.float32):
         return self.mod.init_params(self.cfg, key, dtype)
 
-    def init_abstract(self, dtype=jnp.bfloat16):
-        """Parameter ShapeDtypeStructs without allocating (for dry-runs)."""
-        return jax.eval_shape(
-            lambda k: self.mod.init_params(self.cfg, k, dtype),
-            jax.random.key(0))
-
-    def init_cache(self, batch: int, max_len: int, dtype=jnp.bfloat16,
-                   window: Optional[int] = None):
-        return self.mod.init_cache(self.cfg, batch, max_len, dtype,
-                                   window=window)
+    def init_cache(self, batch: int, max_len: int, dtype=jnp.bfloat16):
+        return self.mod.init_cache(self.cfg, batch, max_len, dtype)
 
     # -- forward passes ------------------------------------------------------
-    def forward_train(self, params, batch, *, window=None, remat=True):
-        return self.mod.forward_train(params, self.cfg, batch, window=window,
-                                      remat=remat)
+    def forward_train(self, params, batch):
+        return self.mod.forward_train(params, self.cfg, batch)
 
-    def prefill(self, params, batch, cache, *, window=None):
-        return self.mod.prefill(params, self.cfg, batch, cache, window=window)
+    def prefill(self, params, batch, cache):
+        return self.mod.prefill(params, self.cfg, batch, cache)
 
-    def decode_step(self, params, token, cache, *, window=None):
-        return self.mod.decode_step(params, self.cfg, token, cache,
-                                    window=window)
-
-    # -- losses ---------------------------------------------------------------
-    def loss(self, params, batch, *, window=None, remat=True):
-        """Causal LM loss: tokens predict labels; labels < 0 are masked."""
-        logits, aux = self.forward_train(params, batch, window=window,
-                                         remat=remat)
-        labels = batch["labels"]
-        lse = jax.scipy.special.logsumexp(logits.astype(jnp.float32), axis=-1)
-        gold = jnp.take_along_axis(
-            logits, jnp.maximum(labels, 0)[..., None], axis=-1
-        )[..., 0].astype(jnp.float32)
-        nll = lse - gold
-        mask = (labels >= 0).astype(jnp.float32)
-        loss = jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
-        return loss + MOE_AUX_WEIGHT * aux
-
-
-def make_batch_specs(cfg: ModelConfig, batch: int, seq: int,
-                     dtype=jnp.bfloat16, with_labels: bool = True):
-    """ShapeDtypeStruct stand-ins for a training/prefill batch."""
-    specs = {"tokens": jax.ShapeDtypeStruct((batch, seq), jnp.int32)}
-    if with_labels:
-        specs["labels"] = jax.ShapeDtypeStruct((batch, seq), jnp.int32)
-    if cfg.is_encoder_decoder:
-        specs["enc_feats"] = jax.ShapeDtypeStruct(
-            (batch, cfg.encoder_seq, cfg.d_model), dtype)
-    return specs
+    def decode_step(self, params, token, cache):
+        return self.mod.decode_step(params, self.cfg, token, cache)
 
 
 def make_demo_batch(cfg: ModelConfig, batch: int, seq: int, key,
@@ -98,7 +58,6 @@ def make_demo_batch(cfg: ModelConfig, batch: int, seq: int, key,
     out = {
         "tokens": jax.random.randint(k1, (batch, seq), 0, cfg.vocab_size),
     }
-    out["labels"] = jnp.roll(out["tokens"], -1, axis=1).at[:, -1].set(-1)
     if cfg.is_encoder_decoder:
         out["enc_feats"] = jax.random.normal(
             k2, (batch, cfg.encoder_seq, cfg.d_model), dtype) * 0.02

@@ -13,8 +13,6 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
-from jax import shard_map
-from jax.sharding import PartitionSpec as P
 
 from repro.kernels.moe_gmm.ops import moe_gmm, plain_ffn
 from repro.kernels.wstream_matmul.ops import wstream_matmul
@@ -264,7 +262,7 @@ def _valid_slots(Sc, pos, window):
 
 
 def _softmax_last(scores):
-    # numerically-stable softmax; reduction over a (possibly sharded) Sc dim
+    # numerically-stable softmax over the cache's Sc dim
     m = jnp.max(scores, axis=-1, keepdims=True)
     e = jnp.exp(scores - m)
     s = jnp.sum(e, axis=-1, keepdims=True)
@@ -319,68 +317,6 @@ def _decode_attention_flat(q, k_cache, v_cache, pos, window):
     out = jnp.einsum("bqt,btc->bqc", probs, v_cache.astype(jnp.float32))
     out = jnp.where(own, out.reshape(B, K, G, K, hd), 0.0).sum(axis=3)
     return out.reshape(B, 1, Hq, hd).astype(q.dtype)
-
-
-def dist_decode_attention(q, k_cache, v_cache, k_new, v_new, pos):
-    """Decode attention with the KV sequence dim sharded across the mesh
-    'kv_seq' axis (flash-decoding across chips, TPU-idiomatic): each shard
-    attends over its local KV chunk and the partial (max, sum, weighted-V)
-    stats are combined with pmax/psum — bytes on the wire are O(B*H*hd),
-    not O(KV). The cache write lands only on the owning shard.
-
-    q, k_new, v_new: (B, 1, Hq|K, hd) replicated over the seq axis;
-    caches: (B, Sc, K, hd) or (B, Sc, K * hd), sharded on dim 1. pos:
-    scalar. Returns (out (B,1,Hq,hd), k_cache, v_cache), the caches in the
-    layout they came in.
-    """
-    if k_cache.ndim == 3:
-        split = k_new.shape[:1] + k_cache.shape[1:2] + k_new.shape[2:]
-        out, kc, vc = dist_decode_attention(
-            q, k_cache.reshape(split), v_cache.reshape(split), k_new, v_new,
-            pos)
-        return out, kc.reshape(k_cache.shape), vc.reshape(v_cache.shape)
-    mesh = SH.mesh()
-    seq_ax = SH.rule("kv_seq")
-    batch_ax = SH.rule("kv_batch")
-    B, _, Hq, hd = q.shape
-    K = k_cache.shape[2]
-    G = Hq // K
-    n = mesh.shape[seq_ax]
-    chunk = k_cache.shape[1] // n
-
-    def body(qb, kc, vc, kn, vn):
-        i = jax.lax.axis_index(seq_ax)
-        off = i * chunk
-        slot = pos - off
-        ok = (slot >= 0) & (slot < chunk)
-        idx = jnp.clip(slot, 0, chunk - 1)
-        kc = kc.at[:, idx].set(jnp.where(ok, kn[:, 0], kc[:, idx]))
-        vc = vc.at[:, idx].set(jnp.where(ok, vn[:, 0], vc[:, idx]))
-        qf = qb.reshape(-1, K, G, hd).astype(jnp.float32)
-        s = jnp.einsum("bkgh,btkh->bkgt", qf, kc.astype(jnp.float32))
-        s = s / math.sqrt(hd)
-        kpos = off + jnp.arange(chunk)
-        s = jnp.where((kpos <= pos)[None, None, None, :], s, -1e30)
-        m_loc = jnp.max(s, axis=-1)
-        m = jax.lax.pmax(m_loc, seq_ax)                    # (b,K,G)
-        e = jnp.exp(s - m[..., None])
-        l = jax.lax.psum(jnp.sum(e, axis=-1), seq_ax)      # (b,K,G)
-        o = jnp.einsum("bkgt,btkh->bkgh", e, vc.astype(jnp.float32))
-        o = jax.lax.psum(o, seq_ax) / l[..., None]
-        out = o.reshape(-1, 1, Hq, hd).astype(qb.dtype)
-        return out, kc, vc
-
-    def bspec(*rest):
-        return P(batch_ax, *rest)
-    out, kc, vc = shard_map(
-        body, mesh=mesh,
-        in_specs=(bspec(None, None, None), bspec(seq_ax, None, None),
-                  bspec(seq_ax, None, None), bspec(None, None, None),
-                  bspec(None, None, None)),
-        out_specs=(bspec(None, None, None), bspec(seq_ax, None, None),
-                   bspec(seq_ax, None, None)),
-    )(q, k_cache, v_cache, k_new, v_new)
-    return out, kc, vc
 
 
 def _as_cache_rows(cache, kv):
@@ -476,8 +412,6 @@ def attn_forward(p, x, cfg, *, window=None, causal=True):
     q, k, v = _project_qkv(p, x, cfg, positions, rope=True)
     out = gqa_attention(q, k, v, causal=causal, window=window)
     out = constrain(out, "batch", None, "heads", None)
-    if _tp_axis_ok(cfg.n_heads, "heads"):
-        return tp_attn_out(out, p["wo"], cfg)
     return proj(out.reshape(x.shape[0], S, cfg.q_dim), p["wo"])
 
 
@@ -502,17 +436,11 @@ def attn_decode(p, x, cfg, k_cache, v_cache, pos, *, window=None):
     else:
         positions = posv[:, None]
     q, k, v = _project_qkv(p, x, cfg, positions, rope=True)
-    if SH.rule("kv_seq") is not None and window is None and posv.ndim == 0:
-        # seq-sharded KV: explicit flash-decoding across chips
-        out, k_cache, v_cache = dist_decode_attention(q, k_cache, v_cache,
-                                                      k, v, pos)
-        out = out.reshape(x.shape[0], 1, cfg.q_dim) @ p["wo"]
-        return out, k_cache, v_cache
     k_cache = cache_update_decode(k_cache, k, pos, window=window)
     v_cache = cache_update_decode(v_cache, v, pos, window=window)
     rest = (None,) * (k_cache.ndim - 2)
-    k_cache = constrain(k_cache, "kv_batch", "kv_seq", *rest)
-    v_cache = constrain(v_cache, "kv_batch", "kv_seq", *rest)
+    k_cache = constrain(k_cache, "kv_batch", None, *rest)
+    v_cache = constrain(v_cache, "kv_batch", None, *rest)
     out = decode_attention(q, k_cache, v_cache, pos, window=window)
     out = proj(out.reshape(x.shape[0], 1, cfg.q_dim), p["wo"])
     return out, k_cache, v_cache
@@ -575,73 +503,6 @@ def conv_decode(p, x, state):
 
 
 # ---------------------------------------------------------------------------
-# explicit-TP projections (bf16 all-reduce)
-# ---------------------------------------------------------------------------
-# XLA all-reduces the f32 matmul accumulator of a sharded contraction before
-# converting to bf16 — doubling TP collective bytes. These shard_map variants
-# convert the local partial product to bf16 *before* the psum, halving the
-# wire bytes (standard TP trade: one bf16 rounding on the partial sums).
-# Enabled by the 'tp_bf16_ar' rule; autodiff through shard_map keeps the
-# backward psums in bf16 too.
-
-def _tp_axis_ok(dim: int, axis_name: str = "d_ff") -> bool:
-    ax = SH.rule(axis_name)
-    m = SH.mesh()
-    return bool(SH.rule("tp_bf16_ar") and ax is not None and m is not None
-                and dim % m.shape[ax] == 0)
-
-
-def tp_mlp_forward(p, x, cfg):
-    """SwiGLU/GeLU FFN with explicit TP over the d_ff axis and bf16 psum."""
-    ax = SH.rule("d_ff")
-    mesh = SH.mesh()
-    batch_ax = SH.rule("batch")
-
-    def body(xl, *ws):
-        if len(ws) == 3:
-            wi, wg, wo = ws
-            h = jax.nn.silu(xl @ wg) * (xl @ wi)
-        else:
-            wi, wo = ws
-            h = jax.nn.gelu(xl @ wi)
-        # bf16-native dot so the psum operand is born bf16 (no convert for
-        # XLA's excess-precision pass to hoist past the collective)
-        y = jax.lax.dot_general(h, wo, (((h.ndim - 1,), (0,)), ((), ())),
-                                preferred_element_type=xl.dtype)
-        return jax.lax.psum(y, ax)
-
-    ws = (p["wi"], p["wg"], p["wo"]) if "wg" in p else (p["wi"], p["wo"])
-    in_specs = [P(batch_ax, None, None)]
-    for w in ws[:-1]:
-        in_specs.append(P(None, ax))
-    in_specs.append(P(ax, None))
-    return shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
-                         out_specs=P(batch_ax, None, None))(x, *ws)
-
-
-def tp_attn_out(out_heads, wo, cfg):
-    """Attention output projection (B,S,Hq,hd)@(Hq*hd,D) with heads sharded
-    over the model axis and a bf16 psum."""
-    ax = SH.rule("heads")
-    mesh = SH.mesh()
-    batch_ax = SH.rule("batch")
-    n = mesh.shape[ax]
-    hd = cfg.head_dim
-
-    def body(ol, wl):
-        B, S, hl, _ = ol.shape
-        y = (ol.reshape(B, S, hl * hd) @ wl).astype(ol.dtype)
-        return jax.lax.psum(y, ax)
-
-    del n
-    return shard_map(
-        body, mesh=mesh,
-        in_specs=(P(batch_ax, None, ax, None), P(ax, None)),
-        out_specs=P(batch_ax, None, None),
-    )(out_heads, wo)
-
-
-# ---------------------------------------------------------------------------
 # MLPs
 # ---------------------------------------------------------------------------
 
@@ -662,8 +523,6 @@ def init_mlp(key, cfg, dtype, d_ff: Optional[int] = None):
 
 
 def mlp_forward(p, x, cfg):
-    if _tp_axis_ok(p["wi"].shape[-1]):
-        return tp_mlp_forward(p, x, cfg)
     if "wg" in p:
         h = jax.nn.silu(proj(x, p["wg"])) * proj(x, p["wi"])
     else:

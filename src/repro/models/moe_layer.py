@@ -17,7 +17,9 @@ themselves) and ``sigmoid`` (independent scores; a learned bias added to
 the scores picks the top-k but never weighs them; the chosen scores are
 renormalized, ``s / (sum s + 1e-6)``).
 
-Returns an aux load-balancing loss (Switch eq. 4) accumulated by the caller.
+``moe_forward`` also returns Switch's load-balancing statistic (eq. 4)
+over all experts. It was a training loss; nothing reads it in serving, and
+it stays only for the callers that unpack the pair.
 """
 from __future__ import annotations
 
@@ -88,8 +90,7 @@ def _held_ffn(xf, g, chosen, wg, wi, wo, lo):
     ys = expert_ffn(xs, wg, wi, wo, offsets)
 
     # back to pair order; rows of experts held elsewhere are undefined, so
-    # they are masked before the gate multiplies them (in the backward
-    # too, where an undefined row times a zero cotangent may be NaN)
+    # they are masked before the gate multiplies them
     pair = jnp.take(ys, jnp.argsort(order), axis=0).reshape(T, k, -1)
     pair = jnp.where(held.reshape(T, k, 1), pair, 0.0) * g[..., None]
     return jnp.sum(pair, axis=1)
@@ -110,7 +111,7 @@ def _expert_axis(cfg, batch_size: int):
 
 
 def moe_forward(p, x, cfg):
-    """x: (B, S, D) -> (out (B, S, D), aux_loss scalar)."""
+    """x: (B, S, D) -> (out (B, S, D), load-balancing statistic)."""
     B, S, D = x.shape
     E, k = cfg.n_experts, cfg.top_k
     lo = cfg.expert_range[0]
@@ -126,9 +127,6 @@ def moe_forward(p, x, cfg):
         ax, tok = axes
 
         def shard(xf, g, chosen, wg, wi, wo):
-            # the tokens, the same on every shard, meet this shard's experts:
-            # said so, their gradients are summed over the axis
-            xf, g = (jax.lax.pcast(a, ax, to="varying") for a in (xf, g))
             first = lo + jax.lax.axis_index(ax) * wg.shape[0]
             return jax.lax.psum(_held_ffn(xf, g, chosen, wg, wi, wo, first),
                                 ax)
@@ -139,7 +137,7 @@ def moe_forward(p, x, cfg):
             xf, g, chosen, p["wg"], p["wi"], p["wo"])
     out = out.astype(x.dtype).reshape(B, S, D)
 
-    # aux load-balance loss (Switch eq. 4), over all experts
+    # load-balancing statistic (Switch eq. 4), over all experts
     frac_tokens = jnp.mean(jnp.sum(jax.nn.one_hot(chosen, E), axis=1), axis=0)
     frac_scores = jnp.mean(scores, axis=0)
     aux = E * jnp.sum(frac_tokens * frac_scores) / k

@@ -5,12 +5,9 @@ Full-period groups are scanned; leftover layers (26 mod 3 = 2) are unrolled.
 Train/prefill runs the linear recurrence with ``jax.lax.associative_scan``
 (parallel, TPU-friendly); decode is the exact one-step recurrence. The
 recurrent state (B, W) plus a (conv_width-1) conv tail is the entire
-"KV cache" of a rec layer — constant in sequence length, which is why this
-family runs ``long_500k`` natively.
+"KV cache" of a rec layer — constant in sequence length.
 """
 from __future__ import annotations
-
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -173,10 +170,10 @@ def _rec_apply(p, x, cfg, state, mode, pos):
     return constrain(x, "batch", None, "d_model"), new_state
 
 
-def _slot_cache(cfg, kind, batch, max_len, dtype, window):
+def _slot_cache(cfg, kind, batch, max_len, dtype):
     if kind == "rec":
         return _rec_state(cfg, batch, dtype)
-    Sc = min(max_len, window or cfg.window or max_len)
+    Sc = min(max_len, cfg.window or max_len)
     def z():
         return jnp.zeros(L.kv_cache_shape(batch, Sc, cfg.n_kv_heads,
                                           cfg.head_dim), dtype)
@@ -206,21 +203,20 @@ def init_params(cfg, key, dtype=jnp.bfloat16):
     }
 
 
-def init_cache(cfg, batch: int, max_len: int, dtype=jnp.bfloat16,
-               window: Optional[int] = None):
+def init_cache(cfg, batch: int, max_len: int, dtype=jnp.bfloat16):
     G, pat, rest = _plan(cfg)
     def stack(c):
         return jax.tree.map(lambda a: jnp.broadcast_to(a, (G, *a.shape)), c)
     return {
-        "slots": tuple(stack(_slot_cache(cfg, k, batch, max_len, dtype, window))
+        "slots": tuple(stack(_slot_cache(cfg, k, batch, max_len, dtype))
                        for k in pat),
-        "rest": tuple(_slot_cache(cfg, k, batch, max_len, dtype, window)
+        "rest": tuple(_slot_cache(cfg, k, batch, max_len, dtype)
                       for k in rest),
         "pos": jnp.zeros((), jnp.int32),
     }
 
 
-def _run_stack(params, x, cfg, mode, cache, remat=False):
+def _run_stack(params, x, cfg, mode, cache):
     G, pat, rest = _plan(cfg)
     apply1 = {"rec": _rec_apply, "attn": _attn_apply}
     pos = cache["pos"] if cache is not None else 0
@@ -235,21 +231,19 @@ def _run_stack(params, x, cfg, mode, cache, remat=False):
             new.append(st)
         return x, tuple(new)
 
-    if remat and mode == "train":
-        body = jax.checkpoint(body, prevent_cse=False)
     caches = cache["slots"] if cache is not None else tuple(
-        _slot_cache(cfg, k, x.shape[0], 0, x.dtype, None) for k in pat)
+        _slot_cache(cfg, k, x.shape[0], 0, x.dtype) for k in pat)
     if mode == "train":
         caches = jax.tree.map(
             lambda a: jnp.broadcast_to(a, (G, *a.shape)), tuple(
-                _slot_cache(cfg, k, x.shape[0], 1, x.dtype, None) for k in pat))
+                _slot_cache(cfg, k, x.shape[0], 1, x.dtype) for k in pat))
     scan = jax.lax.scan if mode == "train" else L.scan_layers
     x, new_slots = scan(body, x, (params["slots"], caches))
     new_rest = []
     rest_caches = cache["rest"] if cache is not None else [None] * len(rest)
     for j, kind in enumerate(rest):
         rc = rest_caches[j] if mode != "train" else \
-            _slot_cache(cfg, kind, x.shape[0], 1, x.dtype, None)
+            _slot_cache(cfg, kind, x.shape[0], 1, x.dtype)
         x, st = apply1[kind](params["rest"][j], x, cfg, rc, mode, pos)
         new_rest.append(st)
     return x, new_slots, tuple(new_rest)
@@ -265,13 +259,13 @@ def _logits(params, x, cfg):
     return constrain(x @ params["unembed"], "batch", None, "vocab")
 
 
-def forward_train(params, cfg, batch, *, window=None, remat=True):
+def forward_train(params, cfg, batch):
     x = _embed(params, batch["tokens"])
-    x, _, _ = _run_stack(params, x, cfg, "train", None, remat=remat)
+    x, _, _ = _run_stack(params, x, cfg, "train", None)
     return _logits(params, x, cfg), jnp.zeros((), jnp.float32)
 
 
-def prefill(params, cfg, batch, cache, *, window=None):
+def prefill(params, cfg, batch, cache):
     tokens = batch["tokens"]
     x = _embed(params, tokens)
     x, slots, rest = _run_stack(params, x, cfg, "prefill", cache)
@@ -280,7 +274,7 @@ def prefill(params, cfg, batch, cache, *, window=None):
                   "pos": jnp.asarray(tokens.shape[1], jnp.int32)}
 
 
-def decode_step(params, cfg, token, cache, *, window=None):
+def decode_step(params, cfg, token, cache):
     if token.ndim == 1:
         token = token[:, None]
     x = _embed(params, token)

@@ -1,9 +1,10 @@
 """Logical-axis sharding annotations (MaxText-style, minimal).
 
-Model code annotates activations with *logical* axis names; the launcher
-installs a rules table mapping logical names -> mesh axes. Outside a rules
-context (CPU smoke tests) annotations are no-ops, so model code is written
-once and runs both places.
+Model code annotates activations with *logical* axis names; a caller that
+runs the model on a mesh installs a rules table mapping logical names ->
+mesh axes (``use_rules``). Outside a rules context (the engine, one chip)
+annotations are no-ops, so model code is written once and runs both
+places.
 """
 from __future__ import annotations
 
@@ -15,18 +16,18 @@ from jax.sharding import PartitionSpec as P
 
 MeshAxes = Union[None, str, Tuple[str, ...]]
 
-# logical axis -> mesh axes. Installed by the launcher.
+# logical axis -> mesh axes, installed by ``use_rules``
 _RULES: Optional[Dict[str, MeshAxes]] = None
 _MESH = None
 
 # Canonical rule sets -------------------------------------------------------
 
-def standard_rules(multi_pod: bool) -> Dict[str, MeshAxes]:
-    """2D (data, model) sharding; batch additionally over the pod axis."""
-    batch = ("pod", "data") if multi_pod else ("data",)
+def standard_rules() -> Dict[str, MeshAxes]:
+    """2D (data, model) sharding."""
+    batch = ("data",)
     return {
         "batch": batch,
-        "seq": None,           # sequence replicated by default (see kv_seq)
+        "seq": None,           # sequence replicated
         "d_model": None,       # activations replicated over model on entry
         "heads": "model",
         "kv_heads": None,      # GQA: kv heads usually < model axis -> replicate
@@ -34,7 +35,6 @@ def standard_rules(multi_pod: bool) -> Dict[str, MeshAxes]:
         "experts": "model",
         "vocab": "model",
         "kv_batch": batch,     # kv-cache batch dim
-        "kv_seq": None,        # set to "model" for seq-sharded long-KV decode
         "lru": "model",        # RG-LRU / mLSTM inner width
     }
 
@@ -73,24 +73,6 @@ def active() -> bool:
 def rule(name: str):
     """Mesh axes mapped to a logical axis (None outside a rules context)."""
     return _RULES.get(name) if _RULES is not None else None
-
-
-def maybe_gather_params(p):
-    """ZeRO-3 / FSDP: when the 'fsdp_gather' rule is set, constrain the
-    current layer's weight slices to replicated — GSPMD materializes an
-    all-gather here (and a reduce-scatter for the grads in the backward),
-    so only one layer's weights are ever live replicated inside the scan."""
-    if _RULES is None or not _RULES.get("fsdp_gather"):
-        return p
-    import jax.numpy as jnp  # noqa: F401
-
-    def repl(x):
-        spec = P(*(None,) * x.ndim)
-        if _MESH is not None:
-            return jax.lax.with_sharding_constraint(
-                x, jax.sharding.NamedSharding(_MESH, spec))
-        return x
-    return jax.tree.map(repl, p)
 
 
 def mesh():

@@ -12,14 +12,12 @@ slot and the last ``conv_width - 1`` values of z for a convolution slot.
 """
 from __future__ import annotations
 
-from typing import Optional
-
 import jax
 import jax.numpy as jnp
 
 from repro.models import layers as L
 from repro.models.moe_layer import init_moe, moe_forward
-from repro.models.sharding import constrain, maybe_gather_params
+from repro.models.sharding import constrain
 
 
 def _periodic(kinds):
@@ -83,14 +81,14 @@ def init_params(cfg, key, dtype=jnp.bfloat16):
     return out
 
 
-def init_cache(cfg, batch: int, max_len: int, dtype=jnp.bfloat16,
-               window: Optional[int] = None):
+def init_cache(cfg, batch: int, max_len: int, dtype=jnp.bfloat16):
     """Per stack, per slot of its period, a leaf stacked over the stack's
     ``G`` groups: an attention slot's ``k`` and ``v`` are ``(G, B, Sc, K,
     hd)`` where ``head_dim`` is a multiple of 128, else ``(G, B, Sc, K *
     hd)`` (``layers.kv_cache_shape``); a convolution slot's ``conv`` is
-    ``(G, B, W - 1, D)``. ``Sc`` is ``max_len``, or the window if shorter."""
-    Sc = min(max_len, window) if window else max_len
+    ``(G, B, W - 1, D)``. ``Sc`` is ``max_len``, or ``cfg.window`` if
+    shorter."""
+    Sc = min(max_len, cfg.window) if cfg.window else max_len
 
     def entry(mixer, G):
         if mixer == "conv":
@@ -115,7 +113,7 @@ def _ffn_apply(slot_p, x, cfg, ffn_kind):
     return L.mlp_forward(slot_p["ffn"], x, cfg), jnp.zeros((), jnp.float32)
 
 
-def _mix(p, h, cfg, mixer, mode, cache, pos, window):
+def _mix(p, h, cfg, mixer, mode, cache, pos):
     """The slot's token mixer: (output, its new cache entry or None)."""
     if mixer == "conv":
         if mode == "decode":
@@ -125,17 +123,17 @@ def _mix(p, h, cfg, mixer, mode, cache, pos, window):
         return a, (None if mode == "train" else
                    {"conv": st.astype(cache["conv"].dtype)})
     if mode == "train":
-        return L.attn_forward(p["attn"], h, cfg, window=window), None
+        return L.attn_forward(p["attn"], h, cfg, window=cfg.window), None
     if mode == "prefill":
         a, kc, vc = L.attn_prefill(p["attn"], h, cfg, cache["k"], cache["v"],
-                                   window=window)
+                                   window=cfg.window)
     else:
         a, kc, vc = L.attn_decode(p["attn"], h, cfg, cache["k"], cache["v"],
-                                  pos, window=window)
+                                  pos, window=cfg.window)
     return a, {"k": kc, "v": vc}
 
 
-def _group_body(cfg, mode: str, window, kinds):
+def _group_body(cfg, mode: str, kinds):
     def body(carry, xs):
         if mode == "train":
             x, aux = carry
@@ -145,9 +143,9 @@ def _group_body(cfg, mode: str, window, kinds):
             slot_params, caches = xs
         new_caches = []
         for i, (mixer, ffn_kind) in enumerate(kinds):
-            p = maybe_gather_params(slot_params[i])
+            p = slot_params[i]
             h = L.apply_norm(x, p["ln1"], cfg.norm, cfg.norm_eps)
-            a, c = _mix(p, h, cfg, mixer, mode, caches[i], pos, window)
+            a, c = _mix(p, h, cfg, mixer, mode, caches[i], pos)
             new_caches.append(c)
             x = x + a
             x = constrain(x, "batch", "seq", "d_model")
@@ -163,18 +161,14 @@ def _group_body(cfg, mode: str, window, kinds):
     return body
 
 
-def _run_stack(params, x, cfg, mode, cache=None, window=None, remat=False):
+def _run_stack(params, x, cfg, mode, cache=None):
     aux = jnp.zeros((), jnp.float32)
     new_cache = {}
     for name, kinds, _ in _stacks(cfg):
-        body = _group_body(cfg, mode, window, kinds)
+        body = _group_body(cfg, mode, kinds)
         if mode == "train":
-            if remat:
-                body = jax.checkpoint(body, prevent_cse=False)
             (x, aux), _ = jax.lax.scan(body, (x, aux), params[name])
             continue
-        if remat and mode == "prefill":
-            body = jax.checkpoint(body, prevent_cse=False)
         (x, aux, _), new_cache[name] = L.scan_layers(
             body, (x, aux, cache["pos"]), (params[name], cache[name]))
     return x, aux, new_cache
@@ -198,29 +192,27 @@ def _logits(params, x, cfg):
     return constrain(logits, "batch", None, "vocab")
 
 
-def forward_train(params, cfg, batch, *, window=None, remat=True):
+def forward_train(params, cfg, batch):
     """Full-sequence forward. Returns (logits (B,S,V), aux_loss)."""
     x = _embed(params, batch["tokens"])
-    x, aux, _ = _run_stack(params, x, cfg, "train", window=window, remat=remat)
+    x, aux, _ = _run_stack(params, x, cfg, "train")
     return _logits(params, x, cfg), aux
 
 
-def prefill(params, cfg, batch, cache, *, window=None):
+def prefill(params, cfg, batch, cache):
     """Process the prompt, fill the cache. Returns (last-token logits, cache)."""
     tokens = batch["tokens"]
     x = _embed(params, tokens)
-    x, _, new_cache = _run_stack(params, x, cfg, "prefill", cache=cache,
-                                 window=window)
+    x, _, new_cache = _run_stack(params, x, cfg, "prefill", cache=cache)
     last = _logits(params, x[:, -1:, :], cfg)[:, 0]
     return last, {**new_cache, "pos": jnp.asarray(tokens.shape[1], jnp.int32)}
 
 
-def decode_step(params, cfg, token, cache, *, window=None):
+def decode_step(params, cfg, token, cache):
     """One decode step. token: (B,) or (B,1). Returns (logits (B,V), cache)."""
     if token.ndim == 1:
         token = token[:, None]
     x = _embed(params, token)
-    x, _, new_cache = _run_stack(params, x, cfg, "decode", cache=cache,
-                                 window=window)
+    x, _, new_cache = _run_stack(params, x, cfg, "decode", cache=cache)
     logits = _logits(params, x, cfg)[:, 0]
     return logits, {**new_cache, "pos": cache["pos"] + 1}

@@ -11,8 +11,6 @@ decode shapes; recorded in DESIGN.md).
 """
 from __future__ import annotations
 
-from typing import Optional
-
 import jax
 import jax.numpy as jnp
 
@@ -56,10 +54,9 @@ def init_params(cfg, key, dtype=jnp.bfloat16):
     }
 
 
-def init_cache(cfg, batch: int, max_len: int, dtype=jnp.bfloat16,
-               window: Optional[int] = None):
+def init_cache(cfg, batch: int, max_len: int, dtype=jnp.bfloat16):
     Ld = cfg.n_layers
-    Sc = min(max_len, window) if window else max_len
+    Sc = min(max_len, cfg.window) if cfg.window else max_len
     def kv(s):
         return jnp.zeros((Ld, batch, s, cfg.n_kv_heads, cfg.head_dim), dtype)
     self_kv = (Ld, *L.kv_cache_shape(batch, Sc, cfg.n_kv_heads, cfg.head_dim))
@@ -99,11 +96,11 @@ def _dec_embed(params, cfg, tokens, pos0):
     return constrain(x, "batch", None, "d_model")
 
 
-def _dec_stack(params, cfg, x, mode, cache, enc_out=None, window=None,
-               remat=False):
+def _dec_stack(params, cfg, x, mode, cache, enc_out=None):
     """mode: train|prefill|decode. For prefill, enc_out is required (cross K/V
     are computed and stored); for decode they are read from the cache."""
     pos = cache["pos"] if cache is not None else 0
+    window = cfg.window
 
     def body(x, xs):
         if mode == "train":
@@ -134,8 +131,6 @@ def _dec_stack(params, cfg, x, mode, cache, enc_out=None, window=None,
         return constrain(x, "batch", None, "d_model"), new
 
     if mode == "train":
-        if remat:
-            body = jax.checkpoint(body, prevent_cse=False)
         x, _ = jax.lax.scan(body, x, params["dec"])
         return x, None
     xs = (params["dec"], (cache["k"], cache["v"], cache["ck"], cache["cv"]))
@@ -148,31 +143,29 @@ def _logits(params, x, cfg):
     return constrain(x @ params["unembed"], "batch", None, "vocab")
 
 
-def forward_train(params, cfg, batch, *, window=None, remat=True):
+def forward_train(params, cfg, batch):
     enc_out = encode(params, cfg, batch["enc_feats"])
     x = _dec_embed(params, cfg, batch["tokens"], 0)
-    x, _ = _dec_stack(params, cfg, x, "train", None, enc_out=enc_out,
-                      window=window, remat=remat)
+    x, _ = _dec_stack(params, cfg, x, "train", None, enc_out=enc_out)
     return _logits(params, x, cfg), jnp.zeros((), jnp.float32)
 
 
-def prefill(params, cfg, batch, cache, *, window=None):
+def prefill(params, cfg, batch, cache):
     enc_out = encode(params, cfg, batch["enc_feats"])
     tokens = batch["tokens"]
     x = _dec_embed(params, cfg, tokens, 0)
-    x, new = _dec_stack(params, cfg, x, "prefill", cache, enc_out=enc_out,
-                        window=window)
+    x, new = _dec_stack(params, cfg, x, "prefill", cache, enc_out=enc_out)
     kc, vc, ck, cv = new
     last = _logits(params, x[:, -1:, :], cfg)[:, 0]
     return last, {"k": kc, "v": vc, "ck": ck, "cv": cv,
                   "pos": jnp.asarray(tokens.shape[1], jnp.int32)}
 
 
-def decode_step(params, cfg, token, cache, *, window=None):
+def decode_step(params, cfg, token, cache):
     if token.ndim == 1:
         token = token[:, None]
     x = _dec_embed(params, cfg, token, cache["pos"])
-    x, new = _dec_stack(params, cfg, x, "decode", cache, window=window)
+    x, new = _dec_stack(params, cfg, x, "decode", cache)
     kc, vc, ck, cv = new
     return _logits(params, x, cfg)[:, 0], {"k": kc, "v": vc, "ck": ck,
                                            "cv": cv, "pos": cache["pos"] + 1}

@@ -14,7 +14,6 @@ disaggregated handoff transfers in one small message.
 from __future__ import annotations
 
 import math
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -265,8 +264,7 @@ def init_params(cfg, key, dtype=jnp.bfloat16):
     }
 
 
-def init_cache(cfg, batch: int, max_len: int, dtype=jnp.bfloat16,
-               window: Optional[int] = None):
+def init_cache(cfg, batch: int, max_len: int, dtype=jnp.bfloat16):
     G = _n_groups(cfg)
     kinds = _slot_kinds(cfg)
     def stack(mk):
@@ -276,7 +274,7 @@ def init_cache(cfg, batch: int, max_len: int, dtype=jnp.bfloat16,
     return {"slots": slots, "pos": jnp.zeros((), jnp.int32)}
 
 
-def _run_stack(params, x, cfg, mode, cache, remat=False):
+def _run_stack(params, x, cfg, mode, cache):
     kinds = _slot_kinds(cfg)
 
     def body(carry, xs):
@@ -290,8 +288,6 @@ def _run_stack(params, x, cfg, mode, cache, remat=False):
             new_states.append(st)
         return x, tuple(new_states)
 
-    if remat and mode == "train":
-        body = jax.checkpoint(body, prevent_cse=False)
     x, new_slots = jax.lax.scan(body, x, (params["slots"], cache["slots"]))
     return x, new_slots
 
@@ -306,14 +302,14 @@ def _logits(params, x, cfg):
     return constrain(x @ params["unembed"], "batch", None, "vocab")
 
 
-def forward_train(params, cfg, batch, *, window=None, remat=True):
+def forward_train(params, cfg, batch):
     x = _embed(params, batch["tokens"])
     cache = init_cache(cfg, x.shape[0], 0, x.dtype)
-    x, _ = _run_stack(params, x, cfg, "train", cache, remat=remat)
+    x, _ = _run_stack(params, x, cfg, "train", cache)
     return _logits(params, x, cfg), jnp.zeros((), jnp.float32)
 
 
-def prefill(params, cfg, batch, cache, *, window=None):
+def prefill(params, cfg, batch, cache):
     tokens = batch["tokens"]
     x = _embed(params, tokens)
     x, new_slots = _run_stack(params, x, cfg, "prefill", cache)
@@ -322,7 +318,7 @@ def prefill(params, cfg, batch, cache, *, window=None):
                   "pos": jnp.asarray(tokens.shape[1], jnp.int32)}
 
 
-def decode_step(params, cfg, token, cache, *, window=None):
+def decode_step(params, cfg, token, cache):
     if token.ndim == 1:
         token = token[:, None]
     x = _embed(params, token)

@@ -1,18 +1,6 @@
 import numpy as np
 import pytest
 
-# Modules whose tests are marked ``slow`` wholesale and run only in the CI
-# slow lane (the fast lane runs ``pytest -m "not slow"``).
-# test_dist_attention spawns a subprocess with 8 XLA host devices and takes
-# ~8 minutes on CPU — by far the longest item in the suite.
-SLOW_MODULES = {"test_dist_attention"}
-
-
-def pytest_collection_modifyitems(config, items):
-    for item in items:
-        if item.module.__name__ in SLOW_MODULES:
-            item.add_marker(pytest.mark.slow)
-
 
 @pytest.fixture(scope="session")
 def rng():

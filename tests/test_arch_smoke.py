@@ -1,12 +1,12 @@
-"""Per-assigned-architecture smoke tests: reduced variant of each family,
-one forward + one train step on CPU, asserting output shapes and no NaNs."""
+"""Per-assigned-architecture smoke tests: reduced variant of each family on
+the CPU, its teacher-forcing forward and its serving path, asserting output
+shapes and no NaNs."""
 import jax
 import jax.numpy as jnp
 import pytest
 
 from repro.configs import ARCH_IDS, get_config
 from repro.models import LM, make_demo_batch
-from repro.training.optimizer import AdamWConfig, apply_updates, init_state
 
 B, S = 2, 24
 
@@ -18,32 +18,20 @@ def key():
 
 @pytest.mark.parametrize("arch", ARCH_IDS)
 def test_forward_and_train_step(arch, key):
+    """The full-sequence forward (``forward_train``): logits for every
+    position, finite, and a finite MoE load-balancing statistic (0 for a
+    model without experts)."""
     cfg = get_config(arch).reduced()
     lm = LM(cfg)
     params = lm.init(key)
     batch = make_demo_batch(cfg, B, S, key)
 
-    logits, aux = lm.forward_train(params, batch, remat=False)
+    logits, aux = lm.forward_train(params, batch)
     assert logits.shape == (B, S, cfg.vocab_size)
-    assert not jnp.isnan(logits).any()
-    assert jnp.isfinite(aux)
-
-    opt_cfg = AdamWConfig(total_steps=10, warmup_steps=1)
-    opt_state = init_state(opt_cfg, params)
-
-    def loss_fn(p):
-        return lm.loss(p, batch, remat=False)
-
-    loss, grads = jax.value_and_grad(loss_fn)(params)
-    assert jnp.isfinite(loss)
-    new_params, _, metrics = apply_updates(opt_cfg, params, grads, opt_state)
-    assert jnp.isfinite(metrics["grad_norm"])
-    # parameters actually moved
-    moved = jax.tree.reduce(
-        lambda a, b: a + b,
-        jax.tree.map(lambda a, b: float(jnp.sum(jnp.abs(a - b))),
-                     params, new_params))
-    assert moved > 0.0
+    assert bool(jnp.isfinite(logits).all())
+    assert aux.shape == () and bool(jnp.isfinite(aux))
+    if not cfg.n_experts:
+        assert float(aux) == 0.0
 
 
 @pytest.mark.parametrize("arch", ARCH_IDS)

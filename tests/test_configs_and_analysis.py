@@ -4,7 +4,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.analysis import hlo_graph as HG
-from repro.configs import ARCH_ALIASES, ARCH_IDS, INPUT_SHAPES, get_config
+from repro.configs import ARCH_ALIASES, ARCH_IDS, get_config
 
 EXPECTED = {
     # arch: (layers, d_model, heads, kv, d_ff, vocab)
@@ -58,13 +58,6 @@ def test_moe_active_params():
 def test_aliases_resolve():
     for alias in ARCH_ALIASES:
         assert get_config(alias) is not None
-
-
-def test_input_shapes_table():
-    s = INPUT_SHAPES
-    assert s["train_4k"].global_batch == 256
-    assert s["long_500k"].seq_len == 524_288
-    assert s["decode_32k"].kind == "decode"
 
 
 def test_reduced_configs_small():

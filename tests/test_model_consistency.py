@@ -39,20 +39,21 @@ def _at_head_dim_128(cfg):
 
 
 def _check_teacher_forcing(cfg, window):
+    if window is not None:
+        cfg = dataclasses.replace(cfg, window=window)
     lm = LM(cfg)
     key = jax.random.key(1)
     params = lm.init(key)
     B, S, P = 2, 24, 16
     batch = make_demo_batch(cfg, B, S, key)
-    full, _ = lm.forward_train(params, batch, remat=False, window=window)
-    cache = lm.init_cache(B, S + 4, dtype=jnp.float32, window=window)
+    full, _ = lm.forward_train(params, batch)
+    cache = lm.init_cache(B, S + 4, dtype=jnp.float32)
     pb = dict(batch)
     pb["tokens"] = batch["tokens"][:, :P]
-    lg, cache = lm.prefill(params, pb, cache, window=window)
+    lg, cache = lm.prefill(params, pb, cache)
     errs = [float(jnp.max(jnp.abs(lg - full[:, P - 1])))]
     for t in range(P, S):
-        lg, cache = lm.decode_step(params, batch["tokens"][:, t], cache,
-                                   window=window)
+        lg, cache = lm.decode_step(params, batch["tokens"][:, t], cache)
         errs.append(float(jnp.max(jnp.abs(lg - full[:, t]))))
     assert max(errs) < 2e-3, (cfg.name, errs)
 

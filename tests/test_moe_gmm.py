@@ -99,32 +99,12 @@ def test_work_items_bound_and_padding():
     assert valid.tolist() == [1, 1, 1, 0, 0]
 
 
-def test_gradient_is_the_plain_grouped_ffn():
-    kx, kw, kg = jax.random.split(jax.random.key(3), 3)
-    offsets = jnp.asarray([0, 5, 5, 12], jnp.int32)
-    x = jax.random.normal(kx, (16, 128), jnp.float32)
-    ws = _weights(kw, 3, 128, 256)
-    g = jax.random.normal(kg, (16, 128), jnp.float32)
-    got = jax.vjp(lambda x, *w: moe_gmm(x, *w, offsets, interpret=True),
-                  x, *ws)[1](g)
-
-    def plain(x, wg, wi, wo):
-        mm = lambda a, w: jax.lax.ragged_dot(a, w, jnp.diff(offsets))  # noqa
-        return mm(jax.nn.silu(mm(x, wg)) * mm(x, wi), wo)
-    want = jax.vjp(plain, x, *ws)[1](g)
-    for a, b in zip(got, want):
-        assert a.shape == b.shape
-        assert float(jnp.max(jnp.abs(a - b))) <= 1e-5 * float(
-            jnp.max(jnp.abs(b)))
-
-
 def test_layer_gradient_through_kernel_ignores_rows_held_elsewhere(
         monkeypatch):
     """The MoE layer on the kernel's path (interpret mode in
     ``expert_ffn``'s place), holding 2 of 4 experts: the kernel leaves the
     rows of pairs routed elsewhere undefined, here NaN, the worst they can
-    hold. The layer's output and its gradients (router, selection bias,
-    experts, input) stay finite and match the plain path's."""
+    hold. The layer's output stays finite and matches the plain path's."""
     import dataclasses
 
     from repro.configs import get_config
@@ -135,12 +115,7 @@ def test_layer_gradient_through_kernel_ignores_rows_held_elsewhere(
     p = moe_layer.init_moe(jax.random.key(7), cfg, jnp.float32)
     p["bias"] = 0.1 * jax.random.normal(jax.random.key(8), p["bias"].shape)
     x = jax.random.normal(jax.random.key(9), (2, 8, cfg.d_model))
-    probe = jax.random.normal(jax.random.key(10), x.shape)
-
-    def loss(p, x):
-        out, _ = moe_layer.moe_forward(p, x, cfg)
-        return jnp.sum(out * probe)
-    want = jax.grad(loss, argnums=(0, 1))(p, x)
+    want, _ = moe_layer.moe_forward(p, x, cfg)
 
     def kernel_ffn(x, wg, wi, wo, offsets):
         y = moe_gmm(x, wg, wi, wo, offsets, interpret=True)
@@ -149,13 +124,10 @@ def test_layer_gradient_through_kernel_ignores_rows_held_elsewhere(
     monkeypatch.setattr(moe_layer, "expert_ffn", kernel_ffn)
     out, _ = moe_layer.moe_forward(p, x, cfg)
     assert bool(jnp.isfinite(out).all())
-    got = jax.grad(loss, argnums=(0, 1))(p, x)
-    # the kernel's forward rounds its operands to bf16 (2^-8 relative) and
-    # the gradients read its outputs; the plain path here is float32
-    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
-        assert bool(jnp.isfinite(a).all())
-        assert float(jnp.max(jnp.abs(a - b))) <= 2e-2 * float(
-            jnp.max(jnp.abs(b)))
+    # the kernel rounds its operands to bf16 (2^-8 relative); the plain
+    # path here is float32
+    assert float(jnp.max(jnp.abs(out - want))) <= 2e-2 * float(
+        jnp.max(jnp.abs(want)))
 
 
 EXPERT_PARALLEL = r"""
@@ -169,34 +141,29 @@ from repro.models.sharding import standard_rules, use_rules
 
 mesh = jax.make_mesh((2, 4), ("data", "model"),
                      axis_types=(AxisType.Auto, AxisType.Auto))
-rules = standard_rules(False)
+rules = standard_rules()
 for arch in ("phi3_5_moe", "lfm2_24b_a2b"):
     cfg = get_config(arch).reduced()
     p = moe_layer.init_moe(jax.random.key(1), cfg, jnp.float32)
     if "bias" in p:
         p["bias"] = 0.1 * jax.random.normal(jax.random.key(2), p["bias"].shape)
     x = jax.random.normal(jax.random.key(3), (4, 6, cfg.d_model))
-    probe = jax.random.normal(jax.random.key(4), x.shape)
+    want, _ = moe_layer.moe_forward(p, x, cfg)
 
-    def loss(p, x, sharded):
-        if not sharded:
-            return jnp.sum(moe_layer.moe_forward(p, x, cfg)[0] * probe)
+    def sharded(p, x):
         with use_rules(rules, mesh):
             assert moe_layer._expert_axis(cfg, x.shape[0]) is not None
-            return jnp.sum(moe_layer.moe_forward(p, x, cfg)[0] * probe)
-    want = jax.value_and_grad(loss, argnums=(0, 1))(p, x, False)
+            return moe_layer.moe_forward(p, x, cfg)[0]
     ps = {k: jax.device_put(v, NamedSharding(
               mesh, P("model", None, None) if k in ("wi", "wg", "wo")
               else P())) for k, v in p.items()}
     with mesh:
-        text = jax.jit(lambda p, x: loss(p, x, True)).lower(ps, x).compile(
-            ).as_text()
-        got = jax.jit(jax.value_and_grad(
-            lambda p, x: loss(p, x, True), argnums=(0, 1)))(ps, x)
+        step = jax.jit(sharded)
+        text = step.lower(ps, x).compile().as_text()
+        got = step(ps, x)
     assert "all-reduce" in text
-    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
-        err = float(jnp.max(jnp.abs(a - b)))
-        assert err <= 1e-5 * float(jnp.max(jnp.abs(b))), (arch, err)
+    err = float(jnp.max(jnp.abs(got - want)))
+    assert err <= 1e-5 * float(jnp.max(jnp.abs(want))), (arch, err)
 print("EXPERT_PARALLEL_OK")
 """
 
@@ -205,9 +172,9 @@ def test_expert_parallel_layer_matches_the_whole_layer():
     """Under sharding rules that put the 4 experts of the reduced
     phi3_5_moe (softmax router) and lfm2 (sigmoid router with a selection
     bias) on a 4-way model axis, each shard computes its own expert's pairs
-    and one all-reduce joins them: the loss and every gradient equal the
-    unsharded layer's, up to float32 sums in another order. Runs in a
-    subprocess on 8 host devices."""
+    and one all-reduce joins them: the output equals the unsharded layer's,
+    up to float32 sums in another order. Runs in a subprocess on 8 host
+    devices."""
     import subprocess
     import sys
     r = subprocess.run([sys.executable, "-c", EXPERT_PARALLEL],

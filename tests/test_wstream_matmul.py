@@ -43,23 +43,6 @@ def test_kernel_matches_bf16_dot(M, K, N):
                                  transposed=True, **small), ref)
 
 
-def test_gradient_is_the_plain_matmuls():
-    kx, kw, kg = jax.random.split(jax.random.key(3), 3)
-    x = jax.random.normal(kx, (2, 3, 256), jnp.float32)
-    w = jax.random.normal(kw, (256, 384), jnp.float32)
-    g = jax.random.normal(kg, (2, 3, 384), jnp.float32)
-
-    def vjp(f):
-        return jax.vjp(f, x, w)[1](g)
-
-    got = vjp(lambda x, w: wstream_matmul(x, w, interpret=True))
-    want = vjp(lambda x, w: x @ w)
-    for a, b in zip(got, want):
-        assert a.shape == b.shape
-        assert float(jnp.max(jnp.abs(a - b))) <= 1e-5 * float(
-            jnp.max(jnp.abs(b)))
-
-
 def test_proj_is_exactly_matmul_on_cpu():
     kx, kw = jax.random.split(jax.random.key(5))
     x = jax.random.normal(kx, (2, 3, 256), jnp.float32)
@@ -73,6 +56,23 @@ def test_proj_is_exactly_matmul_on_cpu():
     # other dtypes keep the plain matmul
     wb = w.astype(jnp.bfloat16)
     assert (L.proj(x, wb) == x @ wb).all()
+
+
+def test_transposed_proj_is_exactly_matmul_on_cpu():
+    """``proj(..., transposed=True)`` (the tied unembedding) is ``x @ w.T``
+    on the CPU, for a plain (N, K) weight and a layer of an (L, N, K)
+    stack read in place."""
+    kx, kw = jax.random.split(jax.random.key(6))
+    x = jax.random.normal(kx, (2, 3, 256), jnp.float32)
+    stack = jax.random.normal(kw, (3, 384, 256), jnp.float32)
+    w = stack[2]
+    assert (L.proj(x, w, transposed=True) == x @ w.T).all()
+    # compiled, XLA:CPU contracts a transposed operand in another order
+    # than the eager product: compare with the compiled product
+    want = jax.jit(lambda x, w: x @ w.T)(x, w)
+    view = jax.jit(lambda x, s, i: L.proj(x, L.LayerWeight(s, i),
+                                          transposed=True))
+    assert (view(x, stack, 2) == want).all()
 
 
 def test_layer_params_views_only_dense_projections():
